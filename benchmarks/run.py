@@ -43,6 +43,9 @@ def main(argv=None) -> None:
                     help="output path for the online-maintenance perf JSON")
     args = ap.parse_args(argv)
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import tables
     benches = [
         ("datasets", tables.bench_datasets),              # Table I

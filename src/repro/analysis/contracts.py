@@ -185,28 +185,28 @@ def default_contracts() -> list[KernelContract]:
             name="nng_tile_hamming",
             kernel_trace=lambda: (
                 lambda x, y, v: nt.nng_tile_hamming_pallas(
-                    x, y, v, _EPS_HAM, tq=128, tp=256, wchunk=8),
+                    x, y, v, _EPS_HAM, tq=128, tp=256),
                 (_sds((128, 8), u32), _sds((256, 8), u32), _sds((256,), i32))),
             oracle_trace=lambda: (
                 lambda x, y, v: nt.nng_tile_hamming_ref(x, y, v, _EPS_HAM),
                 (_sds((128, 8), u32), _sds((256, 8), u32), _sds((256,), i32))),
             canonical_thresholds=(),  # integer threshold — exact by nature
             shape_invariants=((128, 128, "q % tq"), (256, 256, "p % tp"),
-                              (256, 32, "tp % 32"), (8, 8, "w % wchunk")),
+                              (256, 32, "tp % 32")),
             out_dtypes=(i32, u32),
         ),
         KernelContract(
             name="nng_tile_l1",
             kernel_trace=lambda: (
                 lambda x, y, v: nt.nng_tile_l1_pallas(
-                    x, y, v, _EPS_L2, tq=128, tp=256, cchunk=8),
+                    x, y, v, _EPS_L2, tq=128, tp=256),
                 (_sds((128, 8), f32), _sds((256, 8), f32), _sds((256,), i32))),
             oracle_trace=lambda: (
                 lambda x, y, v: nt.nng_tile_l1_ref(x, y, v, _EPS_L2),
                 (_sds((128, 8), f32), _sds((256, 8), f32), _sds((256,), i32))),
             canonical_thresholds=(eps_f32,),
             shape_invariants=((128, 128, "q % tq"), (256, 256, "p % tp"),
-                              (256, 32, "tp % 32"), (8, 8, "d % cchunk")),
+                              (256, 32, "tp % 32")),
             out_dtypes=(i32, u32),
         ),
         KernelContract(
@@ -234,7 +234,7 @@ def default_contracts() -> list[KernelContract]:
                 lambda x, y, xg, yg, xi, yi:
                 nt.nng_tile_grouped_hamming_pallas(
                     x, y, xg, yg, xi, yi, _EPS_HAM,
-                    tq=128, tp=256, wchunk=8),
+                    tq=128, tp=256),
                 (_sds((128, 8), u32), _sds((256, 8), u32),
                  _sds((128,), i32), _sds((256,), i32),
                  _sds((128,), i32), _sds((256,), i32))),
@@ -246,7 +246,7 @@ def default_contracts() -> list[KernelContract]:
                  _sds((128,), i32), _sds((256,), i32))),
             canonical_thresholds=(),
             shape_invariants=((128, 128, "q % tq"), (256, 256, "p % tp"),
-                              (256, 32, "tp % 32"), (8, 8, "w % wchunk")),
+                              (256, 32, "tp % 32")),
             out_dtypes=(i32, u32),
         ),
         KernelContract(
@@ -254,7 +254,7 @@ def default_contracts() -> list[KernelContract]:
             kernel_trace=lambda: (
                 lambda x, y, xg, yg, xi, yi: nt.nng_tile_grouped_l1_pallas(
                     x, y, xg, yg, xi, yi, _EPS_L2,
-                    tq=128, tp=256, cchunk=8),
+                    tq=128, tp=256),
                 (_sds((128, 8), f32), _sds((256, 8), f32),
                  _sds((128,), i32), _sds((256,), i32),
                  _sds((128,), i32), _sds((256,), i32))),
@@ -266,7 +266,7 @@ def default_contracts() -> list[KernelContract]:
                  _sds((128,), i32), _sds((256,), i32))),
             canonical_thresholds=(eps_f32,),
             shape_invariants=((128, 128, "q % tq"), (256, 256, "p % tp"),
-                              (256, 32, "tp % 32"), (8, 8, "d % cchunk")),
+                              (256, 32, "tp % 32")),
             out_dtypes=(i32, u32),
         ),
         KernelContract(
@@ -290,7 +290,7 @@ def default_contracts() -> list[KernelContract]:
             name="nng_tile_ghost_hamming",
             kernel_trace=lambda: (
                 lambda x, y, gb, yg: nt.nng_tile_ghost_hamming_pallas(
-                    x, y, gb, yg, _EPS_HAM, tq=128, tp=256, wchunk=8),
+                    x, y, gb, yg, _EPS_HAM, tq=128, tp=256),
                 (_sds((128, 8), u32), _sds((256, 8), u32),
                  _sds((128, 1), u32), _sds((256,), i32))),
             oracle_trace=lambda: (
@@ -300,14 +300,14 @@ def default_contracts() -> list[KernelContract]:
                  _sds((128, 1), u32), _sds((256,), i32))),
             canonical_thresholds=(),
             shape_invariants=((128, 128, "q % tq"), (256, 256, "p % tp"),
-                              (256, 32, "tp % 32"), (8, 8, "w % wchunk")),
+                              (256, 32, "tp % 32")),
             out_dtypes=(i32, u32),
         ),
         KernelContract(
             name="nng_tile_ghost_l1",
             kernel_trace=lambda: (
                 lambda x, y, gb, yg: nt.nng_tile_ghost_l1_pallas(
-                    x, y, gb, yg, _EPS_L2, tq=128, tp=256, cchunk=8),
+                    x, y, gb, yg, _EPS_L2, tq=128, tp=256),
                 (_sds((128, 8), f32), _sds((256, 8), f32),
                  _sds((128, 1), u32), _sds((256,), i32))),
             oracle_trace=lambda: (
@@ -317,7 +317,7 @@ def default_contracts() -> list[KernelContract]:
                  _sds((128, 1), u32), _sds((256,), i32))),
             canonical_thresholds=(eps_f32,),
             shape_invariants=((128, 128, "q % tq"), (256, 256, "p % tp"),
-                              (256, 32, "tp % 32"), (8, 8, "d % cchunk")),
+                              (256, 32, "tp % 32")),
             out_dtypes=(i32, u32),
         ),
         KernelContract(
@@ -342,7 +342,7 @@ def default_contracts() -> list[KernelContract]:
             kernel_trace=lambda: (
                 lambda q, c, rad, leaf, act: tf.tree_frontier_hamming_pallas(
                     q, c, rad, leaf, act, _EPS_HAM,
-                    tq=128, tn=256, wchunk=8),
+                    tq=128, tn=256),
                 (_sds((128, 8), u32), _sds((256, 8), u32), _sds((256,), f32),
                  _sds((256,), i32), _sds((128, 8), u32))),
             oracle_trace=lambda: (
@@ -352,7 +352,7 @@ def default_contracts() -> list[KernelContract]:
                  _sds((256,), i32), _sds((128, 8), u32))),
             canonical_thresholds=(),
             shape_invariants=((128, 128, "nq % tq"), (256, 256, "N % tn"),
-                              (256, 32, "tn % 32"), (8, 8, "w % wchunk")),
+                              (256, 32, "tn % 32")),
             out_dtypes=(u32, u32),
         ),
         KernelContract(
@@ -360,7 +360,7 @@ def default_contracts() -> list[KernelContract]:
             kernel_trace=lambda: (
                 lambda q, c, rad, leaf, act: tf.tree_frontier_l1_pallas(
                     q, c, rad, leaf, act, _EPS_L2,
-                    tq=128, tn=256, cchunk=8),
+                    tq=128, tn=256),
                 (_sds((128, 8), f32), _sds((256, 8), f32), _sds((256,), f32),
                  _sds((256,), i32), _sds((128, 8), u32))),
             oracle_trace=lambda: (
@@ -370,19 +370,19 @@ def default_contracts() -> list[KernelContract]:
                  _sds((256,), i32), _sds((128, 8), u32))),
             canonical_thresholds=(eps_f32,),
             shape_invariants=((128, 128, "nq % tq"), (256, 256, "N % tn"),
-                              (256, 32, "tn % 32"), (8, 8, "d % cchunk")),
+                              (256, 32, "tn % 32")),
             out_dtypes=(u32, u32),
         ),
         KernelContract(
             name="bits_to_cols",
             kernel_trace=lambda: (
-                lambda b: be.bits_to_cols_pallas(b, 128, tq=128, kc=128),
-                (_sds((128, 4), u32),)),
+                lambda b: be.bits_to_cols_pallas(b, 128, tq=128),
+                (_sds((128, 128), u32),)),
             oracle_trace=lambda: (
                 lambda b: be.bits_to_cols_ref(b, 128),
-                (_sds((128, 4), u32),)),
+                (_sds((128, 128), u32),)),
             canonical_thresholds=(),
-            shape_invariants=((128, 128, "m % tq"), (128, 128, "k % kc")),
+            shape_invariants=((128, 128, "m % tq"), (128, 128, "w % 128")),
             out_dtypes=(i32,),
         ),
         KernelContract(

@@ -5,8 +5,8 @@
 bodies, cond branches, pjit/shard_map/pallas_call inner programs — yielding
 ``(eqn, mult)`` where ``mult`` is the static execution-count multiplier.
 
-Multipliers matter for the traffic audit: on jax 0.4.37 a ``fori_loop``
-with static bounds lowers to ``scan`` carrying its trip count in
+Multipliers matter for the traffic audit: a ``fori_loop`` with static
+bounds lowers to ``scan`` carrying its trip count in
 ``params["length"]``, so a ``ppermute`` inside a ring loop contributes
 ``rounds`` hops, not one. In-order matters for channel classification: the
 equation order of a traced jaxpr follows the python call order of the
@@ -20,7 +20,7 @@ programs contain none — every loop is a static-bound ``fori_loop``).
 from __future__ import annotations
 
 import numpy as np
-from jax import core as jcore
+from repro.compat import jcore
 
 __all__ = ["iter_eqns", "iter_jaxprs", "outvar_producer", "literal_float",
            "resolve_scalar", "resolve_scalar_float", "aval_nbytes", "EqnWalk"]

@@ -7,7 +7,7 @@ passes are pure jaxpr inspection — nothing executes.
 from __future__ import annotations
 
 import numpy as np
-from jax import core as jcore
+from repro.compat import jcore
 
 from .diagnostics import Diagnostic
 from .jaxpr_walk import (iter_eqns, iter_jaxprs, outvar_producer,

@@ -86,8 +86,7 @@ from repro.core.metrics import get_metric
 from repro.kernels import (nng_tile_bits, nng_tile_bits_ghost,
                            nng_tile_bits_grouped, nng_tile_bits_pair,
                            nng_tile_geometry, tree_frontier_step)
-from repro.kernels.nng_tile import _pack_words
-from repro.kernels.tree_frontier import _unpack_words
+from repro.kernels.nng_tile import _pack_words, _unpack_words
 from repro.kernels.ops import pallas_mode as _pallas_mode
 # fused bitmask→ids epilogues (repro.kernels.bits_epilogue): rank-selection
 # over word popcounts in VMEM replaces the old two-pass ``lax.top_k``
@@ -128,6 +127,15 @@ class DeviceForest(NamedTuple):
 # repro.kernels provides the hand-tiled Pallas equivalents for TPU hot spots)
 # ---------------------------------------------------------------------------
 
+def _on_ring(mesh: Mesh, axis: str, a, dtype=None):
+    """Place ``a`` block-sharded along its leading axis over the ring, one
+    block per device (not whole on the default device for the jitted
+    program to reshard). Device arrays move device to device."""
+    a = (jnp.asarray(a, dtype) if isinstance(a, jax.Array)
+         else np.asarray(a, dtype))
+    return jax.device_put(a, NamedSharding(mesh, P(axis)))
+
+
 def tile_cdist(x, y, metric):
     """Comparable distances between tiles — the registered metric's device
     ``cdist`` (sq-L2 fp32 for euclidean, counts for hamming, |diff| sums
@@ -148,6 +156,13 @@ def _merge_ids(buf, new_ids):
 def _popcount_rows(bits):
     """Exact per-row hit counts from the packed bitmask -> (m,) int32."""
     return jnp.sum(jax.lax.population_count(bits).astype(jnp.int32), axis=-1)
+
+
+# Each traversal level holds dense (queries x level width) masks and a
+# (queries x leaf slots) range-delta accumulator: queries are traversed in
+# blocks that keep every such array under this many elements (512 MiB of
+# int32). At 2^17 queries x 2^17 leaves an unblocked delta alone is 64 GiB.
+_TRAVERSE_BLOCK_ELEMS = 1 << 27
 
 
 def tree_traverse(qp, qids, qcells, forest: DeviceForest, eps, k_cap: int,
@@ -174,7 +189,10 @@ def tree_traverse(qp, qids, qcells, forest: DeviceForest, eps, k_cap: int,
          end turns the deltas into the per-query leaf coverage mask.
 
     Self pairs are excluded by global-id inequality (qids vs leaf_ids),
-    mirroring the grouped tile kernel's structural exclusion.
+    mirroring the grouped tile kernel's structural exclusion. Large query
+    sets run as a ``lax.map`` over power-of-two query blocks sized by
+    ``_TRAVERSE_BLOCK_ELEMS``; padding queries have no scope, and no
+    query's result depends on the blocking.
 
     Returns (nbrs (nq, k_cap) sorted SENTINEL-padded ids, cnt (nq,) exact
     counts, dists_evaluated, nodes_pruned) — the counters are float32
@@ -184,10 +202,38 @@ def tree_traverse(qp, qids, qcells, forest: DeviceForest, eps, k_cap: int,
     pairs whose subtree was discarded after that single distance.
     """
     nq = qp.shape[0]
+    _, N = forest.radius.shape
+    width = max(N, forest.leaf_ids.shape[0] + 1)
+    qb = max(_TRAVERSE_BLOCK_ELEMS // width, 128)
+    qb = 1 << (qb.bit_length() - 1)
+    ghost = qghost_bits is not None
+    # padding query rows: cell -1 / empty ghost set, so nothing is in scope
+    scope, fill = ((qghost_bits, 0) if ghost
+                   else (jnp.asarray(qcells, jnp.int32), -1))
+    if nq <= qb:
+        return _traverse_block(qp, qids, scope, ghost, forest, eps, k_cap,
+                               metric)
+    nb = -(-nq // qb)
+
+    def blocks(a, value):
+        a = jnp.concatenate(
+            [a, jnp.full((nb * qb - nq,) + a.shape[1:], value, a.dtype)])
+        return a.reshape((nb, qb) + a.shape[1:])
+
+    nbrs, cnt, dists, pruned = jax.lax.map(
+        lambda b: _traverse_block(*b, ghost, forest, eps, k_cap, metric),
+        (blocks(qp, 0), blocks(qids, -1), blocks(scope, fill)))
+    return (nbrs.reshape(nb * qb, k_cap)[:nq], cnt.reshape(-1)[:nq],
+            jnp.sum(dists), jnp.sum(pruned))
+
+
+def _traverse_block(qp, qids, scope, ghost: bool, forest: DeviceForest, eps,
+                    k_cap: int, metric: str):
+    """``tree_traverse`` over one query block; ``scope`` is the (nq,) cell
+    ids, or with ``ghost`` the (nq, ceil(m/32)) packed ghost cell sets."""
+    nq = qp.shape[0]
     L, N = forest.radius.shape
     n_leaf = forest.leaf_ids.shape[0]
-    if qghost_bits is None:
-        qcells = jnp.asarray(qcells, jnp.int32)
 
     ones = jnp.full((nq, N // 32), jnp.uint32(0xFFFFFFFF))
     delta0 = jnp.zeros((nq, n_leaf + 1), jnp.int32)
@@ -199,12 +245,12 @@ def tree_traverse(qp, qids, qcells, forest: DeviceForest, eps, k_cap: int,
         pb = (parent % 32).astype(jnp.uint32)
         pwords = jnp.take(prev_bits, pw, axis=1)            # (nq, N)
         pbit = ((pwords >> pb[None, :]) & 1) == 1
-        if qghost_bits is None:
-            in_scope = cell[None, :] == qcells[:, None]
-        else:
+        if ghost:
             c = jnp.maximum(cell, 0)
-            cw = jnp.take(qghost_bits, c // 32, axis=1)     # (nq, N)
+            cw = jnp.take(scope, c // 32, axis=1)           # (nq, N)
             in_scope = ((cw >> (c % 32).astype(jnp.uint32)[None, :]) & 1) == 1
+        else:
+            in_scope = cell[None, :] == scope[:, None]
         active = pbit & (cell[None, :] >= 0) & in_scope
         act_bits = _pack_words(active)
         emit_bits, exp_bits = tree_frontier_step(
@@ -770,7 +816,7 @@ def systolic_run(
     nranks = mesh.shape[axis]
     n, _ = points.shape
     assert n % nranks == 0, (n, nranks)
-    ids = jnp.arange(n, dtype=jnp.int32)
+    ids = np.arange(n, dtype=np.int32)
     if traversal == "tree" and overlap and ring_schedule is None:
         ring_schedule = plan_ring_schedule(points, nranks, float(eps),
                                            metric=metric, prune=prune)
@@ -779,10 +825,11 @@ def systolic_run(
     fn = _systolic_fn(mesh, float(eps), met, k_cap, axis, prune,
                       _pallas_mode(), traversal, overlap, ring_modes,
                       forest_backend)
-    points = jnp.asarray(points, met.dtype)
+    points = _on_ring(mesh, axis, points, met.dtype)
+    ids = _on_ring(mesh, axis, ids)
     if traversal == "tree":
         assert forest is not None, "traversal='tree' needs stacked forests"
-        ftabs = DeviceForest.from_tables(forest)
+        ftabs = [_on_ring(mesh, axis, forest[k]) for k in DeviceForest._fields]
         return fn(points, ids, *ftabs)
     return fn(points, ids)
 
@@ -862,7 +909,7 @@ def delta_traverse_run(qp, qids, forest: dict, eps, mesh: Mesh, *,
     words = max_cell // 32 + 1
     qbits = jnp.full((nq, words), jnp.uint32(0xFFFFFFFF))
     fn = _delta_fn(mesh, float(eps), met, k_cap, axis, _pallas_mode())
-    ftabs = DeviceForest.from_tables(forest)
+    ftabs = [_on_ring(mesh, axis, forest[k]) for k in DeviceForest._fields]
     nbrs, cnt, dists, pruned = fn(
         jnp.asarray(qp, met.dtype), jnp.asarray(qids, jnp.int32), qbits,
         *ftabs)
@@ -1009,7 +1056,7 @@ def plan_landmark_device(
     n, _ = points.shape
     assert n % nranks == 0, (n, nranks)
     fn = _plan_count_fn(mesh, float(eps), met, axis, _pallas_mode())
-    coal, ghost, gpp, rank_tot = fn(jnp.asarray(points, met.dtype),
+    coal, ghost, gpp, rank_tot = fn(_on_ring(mesh, axis, points, met.dtype),
                                     jnp.asarray(centers, met.dtype),
                                     jnp.asarray(f, jnp.int32))
     return LandmarkPlan(
@@ -1379,7 +1426,7 @@ def landmark_run(
     nranks = mesh.shape[axis]
     n, _ = points.shape
     assert n % nranks == 0, (n, nranks)
-    ids = jnp.arange(n, dtype=jnp.int32)
+    ids = _on_ring(mesh, axis, np.arange(n, dtype=np.int32))
     assert ghost_mode in ("coll", "ring"), (
         f"ghost_mode={ghost_mode!r}: 'auto' is resolved upstream "
         "(resolve_ghost_mode) — the engine compiles one mode")
@@ -1389,16 +1436,16 @@ def landmark_run(
             "plan_landmark_device, or set cap_rank explicitly)")
     fn = _landmark_fn(mesh, float(eps), met, plan, axis, _pallas_mode(),
                       traversal, forest_backend, ghost_mode)
-    points = jnp.asarray(points, met.dtype)
+    points = _on_ring(mesh, axis, points, met.dtype)
     centers = jnp.asarray(centers, met.dtype)
     f = jnp.asarray(f, jnp.int32)
     if traversal == "tree":
         assert forest is not None, "traversal='tree' needs stacked forests"
         assert cell is not None, ("traversal='tree' needs the cell "
                                   "assignment the forests were built from")
-        ftabs = DeviceForest.from_tables(forest)
+        ftabs = [_on_ring(mesh, axis, forest[k]) for k in DeviceForest._fields]
         return fn(points, ids, centers, f,
-                  jnp.asarray(cell, jnp.int32), *ftabs)
+                  _on_ring(mesh, axis, cell, np.int32), *ftabs)
     return fn(points, ids, centers, f)
 
 

@@ -527,7 +527,7 @@ def flatten_covertree(tree: "CoverTree") -> FlatCoverTree:
 
 def build_block_forests(
     points: np.ndarray, nranks: int, metric: str = "euclidean",
-    leaf_size: int = 10, *, backend: str = "host",
+    leaf_size: int = 10, *, backend: str = "host", mesh=None,
 ):
     """Systolic engine: one flat tree per equal contiguous block (rank).
 
@@ -538,12 +538,14 @@ def build_block_forests(
     ``backend="host"`` (the float64 oracle) returns the per-rank
     ``FlatCoverTree`` list; ``backend="device"`` runs the jit builder in
     ``flat_tree_device`` and returns the stacked device-tables dict
-    directly (what ``stack_device_forests`` yields from the host list).
+    directly (what ``stack_device_forests`` yields from the host list),
+    each rank's tables built on its own device of ``mesh`` when given.
     """
     if backend == "device":
         from .flat_tree_device import build_block_forests_device
 
-        return build_block_forests_device(points, nranks, metric, leaf_size)
+        return build_block_forests_device(points, nranks, metric, leaf_size,
+                                          mesh=mesh)
     assert backend == "host", backend
     from .covertree import build_covertree
 
@@ -564,6 +566,7 @@ def build_block_forests(
 def build_cell_forests(
     points: np.ndarray, cell: np.ndarray, f: np.ndarray, nranks: int,
     metric: str = "euclidean", leaf_size: int = 10, *, backend: str = "host",
+    mesh=None,
 ):
     """Landmark engine: per rank, a forest of per-cell cover trees over the
     cells LPT-assigned to it (``f``: cell -> rank). Nodes carry their cell
@@ -571,14 +574,15 @@ def build_cell_forests(
     level-1 cover (PR 2's framing), and the per-cell trees are the in-cell
     levels below it.
 
-    ``backend`` as in ``build_block_forests``: "host" returns the
-    ``FlatCoverTree`` list, "device" the stacked device-tables dict.
+    ``backend`` and ``mesh`` as in ``build_block_forests``: "host"
+    returns the ``FlatCoverTree`` list, "device" the stacked device-tables
+    dict.
     """
     if backend == "device":
         from .flat_tree_device import build_cell_forests_device
 
         return build_cell_forests_device(points, cell, f, nranks, metric,
-                                         leaf_size)
+                                         leaf_size, mesh=mesh)
     assert backend == "host", backend
     from .covertree import build_covertree
 

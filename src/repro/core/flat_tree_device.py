@@ -47,6 +47,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from jax.sharding import PartitionSpec as P
+
+from repro.compat import shard_map
+
 from .metrics import Metric, get_metric
 
 PAD = -1
@@ -280,16 +284,22 @@ def _build_rank_tables(pts, cells, gids, tslot, *, leaf_size: int,
 
 
 @functools.partial(jax.jit, static_argnames=("leaf_size", "max_levels",
-                                             "met"))
+                                             "met", "mesh"))
 def _forest_tables_jit(pts, cells, gids, tslot, *, leaf_size, max_levels,
-                       met):
-    build = functools.partial(_build_rank_tables, leaf_size=leaf_size,
-                              max_levels=max_levels, met=met)
-    return jax.vmap(build)(pts, cells, gids, tslot)
+                       met, mesh):
+    build = jax.vmap(functools.partial(
+        _build_rank_tables, leaf_size=leaf_size, max_levels=max_levels,
+        met=met))
+    if mesh is None:
+        return build(pts, cells, gids, tslot)
+    # one rank's forest per device of the ring, built where it is used
+    spec = P(mesh.axis_names[0])
+    return shard_map(build, mesh, in_specs=(spec,) * 4,
+                     out_specs=spec)(pts, cells, gids, tslot)
 
 
 def _build_stacked(ptsb, cellsb, gidsb, tslotb, met, leaf_size,
-                   max_levels=8, include_child_ranges=False):
+                   max_levels=8, include_child_ranges=False, mesh=None):
     """Run the jit builder, regrow on level overflow, trim empty levels.
 
     Returns the ``stack_device_forests`` dict schema — all jnp arrays with
@@ -297,12 +307,16 @@ def _build_stacked(ptsb, cellsb, gidsb, tslotb, met, leaf_size,
     (``DeviceForest.from_tables``). ``include_child_ranges`` additionally
     keeps ``child_lo``/``child_hi`` (the device traversal is parent-
     pointer-based and doesn't consume them; the structural parity tests
-    do).
+    do). With a 1-D ``mesh`` of ``nranks`` devices, rank r's forest is
+    built on device r; without, all of them on the default device.
     """
+    if mesh is not None:
+        assert ptsb.shape[0] == mesh.size, (ptsb.shape, mesh.size)
     while True:
         out = _forest_tables_jit(ptsb, cellsb, gidsb, tslotb,
                                  leaf_size=int(leaf_size),
-                                 max_levels=int(max_levels), met=met)
+                                 max_levels=int(max_levels), met=met,
+                                 mesh=mesh)
         if not bool(np.any(np.asarray(out["overflow"]))):
             break
         if max_levels >= 512:
@@ -356,12 +370,15 @@ def estimate_max_levels(points, met, sample: int = 256) -> int:
 def build_block_forests_device(points, nranks: int, metric="euclidean",
                                leaf_size: int = 10,
                                max_levels: int | None = None,
-                               *, include_child_ranges: bool = False):
+                               *, include_child_ranges: bool = False,
+                               mesh=None):
     """Systolic engine forests on device: one tree per contiguous block.
 
     Same partitioning contract as ``flat_tree.build_block_forests``;
     returns the stacked device-tables dict (jnp arrays, leading rank axis)
     that ``stack_device_forests`` would produce from the host path.
+    ``mesh`` (the engine's ring, ``nranks`` devices) builds each rank's
+    tree on its own device.
     """
     met = _as_device_metric(metric)
     pts = np.asarray(points)
@@ -381,9 +398,8 @@ def build_block_forests_device(points, nranks: int, metric="euclidean",
         cellsb[r, :n_loc] = 0
         gidsb[r, :n_loc] = np.arange(n_loc, dtype=np.int32) + r * n_loc
         tslotb[r, :n_loc] = 0
-    return _build_stacked(jnp.asarray(ptsb), jnp.asarray(cellsb),
-                          jnp.asarray(gidsb), jnp.asarray(tslotb),
-                          met, leaf_size, max_levels, include_child_ranges)
+    return _build_stacked(ptsb, cellsb, gidsb, tslotb, met, leaf_size,
+                          max_levels, include_child_ranges, mesh)
 
 
 @jax.jit
@@ -487,11 +503,13 @@ def tombstone_stacked_device(tabs, dead_ids):
 def build_cell_forests_device(points, cell, f, nranks: int,
                               metric="euclidean", leaf_size: int = 10,
                               max_levels: int | None = None,
-                              *, include_child_ranges: bool = False):
+                              *, include_child_ranges: bool = False,
+                              mesh=None):
     """Landmark engine forests on device: per rank, one tree per owned
     cell (ascending cell id), nodes stamped with their cell — the same
     forest ``flat_tree.build_cell_forests`` builds on the host. Ranks
     owning no points get the 1-node unmatchable-cell placeholder.
+    ``mesh`` as in ``build_block_forests_device``.
     """
     met = _as_device_metric(metric)
     pts = np.asarray(points)
@@ -530,6 +548,5 @@ def build_cell_forests_device(points, cell, f, nranks: int,
         cellsb[r, :len(m)] = cells_r[r]
         gidsb[r, :len(m)] = m.astype(np.int32)
         tslotb[r, :len(m)] = tslot_r[r]
-    return _build_stacked(jnp.asarray(ptsb), jnp.asarray(cellsb),
-                          jnp.asarray(gidsb), jnp.asarray(tslotb),
-                          met, leaf_size, max_levels, include_child_ranges)
+    return _build_stacked(ptsb, cellsb, gidsb, tslotb, met, leaf_size,
+                          max_levels, include_child_ranges, mesh)

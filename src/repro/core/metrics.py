@@ -55,7 +55,7 @@ class Metric:
     cdist: Callable                  # (x, y) -> (q, p) comparable dists, jnp
     dtype: Any = jnp.float32         # device point dtype
     exact: bool = False              # integer distances: zero-slack compares
-    col_mult: int = 128              # kernel feature-axis pad multiple
+    col_mult: int = 1                # kernel feature-axis pad multiple
     tile_q: int = 256                # fused-tile block shape (full tiles)
     tile_p: int = 512
     # comparable -> true distance on device (None = identity fp32 cast)
@@ -171,16 +171,12 @@ def registered_metrics() -> tuple[str, ...]:
 # ---------------------------------------------------------------------------
 
 def _euclidean_cdist(x, y):
-    """Squared L2 via the fp32 BLAS3 expansion — the SAME arithmetic as the
-    tile kernels' ``_l2_tile_d2``, so knife-edge pairs classify identically
-    everywhere on device."""
-    x = jnp.asarray(x, jnp.float32)
-    y = jnp.asarray(y, jnp.float32)
-    xn = jnp.sum(x * x, axis=-1)[:, None]
-    yn = jnp.sum(y * y, axis=-1)[None, :]
-    d = xn + yn - 2.0 * jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    return jnp.maximum(d, 0.0)
+    """Squared L2 via the fp32 BLAS3 expansion, the tile kernels' own
+    arithmetic (``nng_tile.l2_sq_dists``: canonical norms, the dot at
+    HIGHEST precision; on TPU the default is a single bf16 pass, far
+    outside the fp32 slack the Lemma-1 ghost bound assumes)."""
+    from repro.kernels.nng_tile import l2_sq_dists
+    return jnp.maximum(l2_sq_dists(x, y), 0.0)
 
 
 def _euclidean_true(c):
@@ -274,7 +270,6 @@ def _register_builtins() -> None:
         rowwise=_hamming_rowwise,
         dtype=jnp.uint32,
         exact=True,
-        col_mult=8,
         tile_q=128, tile_p=256,
         tile_pallas=nt.nng_tile_hamming_pallas,
         tile_ref=nt.nng_tile_hamming_ref,
@@ -293,7 +288,6 @@ def _register_builtins() -> None:
         cdist=_l1_cdist,
         rowwise=_l1_rowwise,
         dtype=jnp.float32,
-        col_mult=8,                  # chunked VPU body, like hamming
         tile_q=128, tile_p=256,
         tile_pallas=nt.nng_tile_l1_pallas,
         tile_ref=nt.nng_tile_l1_ref,

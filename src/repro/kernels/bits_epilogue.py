@@ -34,8 +34,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .nng_tile import _pack_words
-from .tree_frontier import _unpack_words
+from .nng_tile import (HIGHEST, _from_lanes, _pack_words, _pack_words_t,
+                       _to_lanes, _unpack_words)
 
 NOCOL = 2**30        # "no more hit columns" padding (device._NOCOL)
 SENTINEL = 2**31 - 1  # neighbor-table padding id
@@ -62,58 +62,88 @@ def bits_to_cols_ref(bits, k: int):
 
 
 def _select_nth_set_bit(word, r):
-    """word (...,) uint32, r (...,) int32 -> bit position of the r-th
-    (0-based) set bit of each word; 32 when the word has <= r set bits."""
-    b = jnp.arange(32, dtype=jnp.uint32)
-    # inclusive prefix mask of bit b; b = 31 wraps to all-ones, as intended
-    mask = (jnp.uint32(2) << b) - jnp.uint32(1)
-    inc = jax.lax.population_count(
-        word[..., None] & mask).astype(jnp.int32)     # (..., 32) nondecreasing
-    return jnp.sum((inc <= r[..., None]).astype(jnp.int32), axis=-1)
+    """word int32/uint32, r int32 (0-based, same shape) -> bit position of
+    the r-th set bit of each word, by a 5-step binary descent over
+    popcounts of the low half of the remaining window (2-D VPU ops only).
+    Meaningless when the word has <= r set bits; callers mask those."""
+    word = jnp.asarray(word, jnp.int32)
+    pos = jnp.zeros_like(r)
+    for s in (16, 8, 4, 2, 1):
+        low = jax.lax.population_count(
+            jax.lax.shift_right_logical(word, pos) & ((1 << s) - 1))
+        up = r >= low
+        r = jnp.where(up, r - low, r)
+        pos = jnp.where(up, pos + s, pos)
+    return pos
 
 
-def _bits_cols_kernel(bits_ref, out_ref, *, kc: int):
-    bits = bits_ref[...]                              # (TQ, W)
-    w = bits.shape[1]
-    pc = jax.lax.population_count(bits).astype(jnp.int32)
-    cumi = jnp.cumsum(pc, axis=1)                     # inclusive word counts
-    cume = cumi - pc                                  # exclusive word counts
-    total = cumi[:, -1]                               # (TQ,)
-    j = pl.program_id(1) * kc + jnp.arange(kc, dtype=jnp.int32)   # (KC,)
-    # word holding output slot j: #\{w : cumi[w] <= j\} (rank selection over
-    # the word popcounts — no sort); set-bit count before it: sum of those
-    # words' popcounts. One (TQ, KC, W) compare cube instead of a gather.
-    lt = (cumi[:, None, :] <= j[None, :, None])       # (TQ, KC, W)
-    wsel = jnp.sum(lt.astype(jnp.int32), axis=-1)     # (TQ, KC)
-    before = jnp.sum(jnp.where(lt, pc[:, None, :], 0), axis=-1)
-    widx = jnp.arange(w, dtype=jnp.int32)
-    word = jnp.sum(
-        jnp.where(widx[None, None, :] == wsel[..., None],
-                  bits[:, None, :], jnp.uint32(0)),
-        axis=-1, dtype=jnp.uint32)                    # (TQ, KC)
-    bit = _select_nth_set_bit(word, j[None, :] - before)
-    col = wsel * 32 + bit
-    out_ref[...] = jnp.where(j[None, :] < total[:, None], col,
-                             jnp.int32(NOCOL))
+_CHUNK = 128     # word rows per triangular prefix-sum block
 
 
-def bits_to_cols_pallas(bits, k: int, *, tq: int = 128, kc: int = 128,
+def _bits_cols_kernel(bits_ref, out_ref, cume_ref, cumi_ref):
+    """Word-major block: bits_ref (W, TQ) int32, one row per lane;
+    out_ref (K, TQ). W % 128 == 0 (the wrapper pads with zero words)."""
+    w, tq = bits_ref.shape
+    k = out_ref.shape[0]
+    # inclusive/exclusive per-word set-bit prefix down the word axis: a
+    # lower-triangular 0/1 MXU contraction per 128-word block plus a carry
+    # (popcounts <= 32 and prefixes < 2^24 are exact in bf16 x bf16 -> f32)
+    lower = (jax.lax.broadcasted_iota(jnp.int32, (_CHUNK, _CHUNK), 0)
+             >= jax.lax.broadcasted_iota(jnp.int32, (_CHUNK, _CHUNK), 1)
+             ).astype(jnp.bfloat16)
+    carry = jnp.zeros((1, tq), jnp.float32)
+    for c in range(w // _CHUNK):
+        rows = slice(c * _CHUNK, (c + 1) * _CHUNK)
+        pc = jax.lax.population_count(bits_ref[rows, :]).astype(jnp.float32)
+        cs = jax.lax.dot_general(
+            lower, pc.astype(jnp.bfloat16), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) + carry
+        cumi_ref[rows, :] = cs.astype(jnp.int32)
+        cume_ref[rows, :] = (cs - pc).astype(jnp.int32)
+        carry = cs[_CHUNK - 1:, :]
+    total = carry.astype(jnp.int32)                   # (1, TQ)
+    widx = jax.lax.broadcasted_iota(jnp.int32, (_CHUNK, tq), 0)
+
+    def slot(j, _):
+        # output slot j lives in the unique word with cume <= j < cumi;
+        # one masked sublane sum per quantity recovers that word, its
+        # index and the set bits before it — no sort, no gather
+        wsel = before = word = jnp.zeros((1, tq), jnp.int32)
+        for c in range(w // _CHUNK):
+            rows = slice(c * _CHUNK, (c + 1) * _CHUNK)
+            ce = cume_ref[rows, :]
+            first = (ce <= j) & (cumi_ref[rows, :] > j)
+            wsel += jnp.sum(jnp.where(first, widx + c * _CHUNK, 0), axis=0,
+                            keepdims=True)
+            before += jnp.sum(jnp.where(first, ce, 0), axis=0, keepdims=True)
+            word += jnp.sum(jnp.where(first, bits_ref[rows, :], 0), axis=0,
+                            keepdims=True)
+        col = wsel * 32 + _select_nth_set_bit(word, j - before)
+        out_ref[pl.ds(j, 1), :] = jnp.where(j < total, col, jnp.int32(NOCOL))
+        return 0
+
+    jax.lax.fori_loop(0, k, slot, 0)
+
+
+def bits_to_cols_pallas(bits, k: int, *, tq: int = 128,
                         interpret: bool = False):
-    """Pallas kernel: same contract as ``bits_to_cols_ref``. Row/slot grid;
-    each program ranks one (tq, kc) output block from the row's words in
-    VMEM. m % tq == 0 and k % kc == 0 (wrappers pad)."""
+    """Pallas kernel: same contract as ``bits_to_cols_ref``. One program per
+    tq-row block; the rows ride the lanes (word-major layout) and the k
+    output slots are ranked one at a time from the row block's per-word
+    prefix counts in VMEM. m % tq == 0, W % 128 == 0 (wrappers pad with
+    zero words)."""
     m, w = bits.shape
-    assert m % tq == 0 and k % kc == 0, (m, tq, k, kc)
-    grid = (m // tq, k // kc)
-    kernel = functools.partial(_bits_cols_kernel, kc=kc)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((tq, w), lambda i, j: (i, 0))],
-        out_specs=pl.BlockSpec((tq, kc), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, k), jnp.int32),
+    assert m % tq == 0 and w % _CHUNK == 0, (m, tq, w)
+    out_t = pl.pallas_call(
+        _bits_cols_kernel,
+        grid=(m // tq,),
+        in_specs=[pl.BlockSpec((w, tq), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((k, tq), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((k, m), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((w, tq), jnp.int32)] * 2,
         interpret=interpret,
-    )(bits)
+    )(_to_lanes(bits))
+    return out_t.T
 
 
 # ---------------------------------------------------------------------------
@@ -142,48 +172,50 @@ def _leaf_pack_kernel(delta_ref, lid_ref, qid_ref, cnt_ref, bits_ref,
 
     d = delta_ref[...].astype(jnp.float32)            # (TQ, TN) exact ints
     tn = d.shape[1]
-    # within-block inclusive prefix sum via a triangular MXU contraction
-    a = jax.lax.broadcasted_iota(jnp.int32, (tn, tn), 0)
-    b = jax.lax.broadcasted_iota(jnp.int32, (tn, tn), 1)
-    tri = (a <= b).astype(jnp.float32)
-    csum = jax.lax.dot_general(
-        d, tri, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) + carry_ref[...]
-    carry_ref[...] = csum[:, -1:]
-    lid = lid_ref[...]
-    cover = ((csum > 0.5)
-             & (lid != SENTINEL)[None, :]
-             & (qid_ref[...][:, None] != lid[None, :]))
-    bits_ref[...] = _pack_words(cover)
-    cnt_ref[...] += jnp.sum(cover.astype(jnp.int32), axis=1)
+    # within-block inclusive prefix sum via a lower-triangular MXU
+    # contraction, produced transposed (leaf slots down the sublanes):
+    # csum_t[n, q] = sum_{m <= n} d[q, m]
+    lower = (jax.lax.broadcasted_iota(jnp.int32, (tn, tn), 0)
+             >= jax.lax.broadcasted_iota(jnp.int32, (tn, tn), 1)
+             ).astype(jnp.float32)
+    csum_t = jax.lax.dot_general(
+        lower, d, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=HIGHEST) + carry_ref[...]
+    carry_ref[...] = csum_t[tn - 1:, :]
+    lid = lid_ref[...]                                # (TN, 1)
+    cover = ((csum_t > 0.5) & (lid != SENTINEL)
+             & (qid_ref[...] != lid))                 # (TN, TQ)
+    bits_ref[...] = _pack_words_t(cover)
+    cnt_ref[...] += jnp.sum(cover.astype(jnp.int32), axis=0, keepdims=True)
 
 
 def leaf_range_pack_pallas(delta, leaf_ids, qids, *, tq: int = 128,
                            tn: int = 512, interpret: bool = False):
     """Pallas kernel: same contract as ``leaf_range_pack_ref``. The leaf
-    axis is the sequential (minor) grid dimension; a (tq, 1) VMEM scratch
+    axis is the sequential (minor) grid dimension; a (1, tq) VMEM scratch
     carries the running prefix sum across column blocks, and the cnt block
     accumulates in place across them. nq % tq == 0, NL % tn == 0,
-    tn % 32 == 0 (wrappers pad)."""
+    tn % 256 == 0 or tn == NL (wrappers pad)."""
     nq, nl = delta.shape
     assert nq % tq == 0 and nl % tn == 0 and tn % 32 == 0, (nq, tq, nl, tn)
-    grid = (nq // tq, nl // tn)
-    return pl.pallas_call(
+    cnt, bits_t = pl.pallas_call(
         _leaf_pack_kernel,
-        grid=grid,
+        grid=(nq // tq, nl // tn),
         in_specs=[
             pl.BlockSpec((tq, tn), lambda i, j: (i, j)),
-            pl.BlockSpec((tn,), lambda i, j: (j,)),
-            pl.BlockSpec((tq,), lambda i, j: (i,)),
+            pl.BlockSpec((tn, 1), lambda i, j: (j, 0)),
+            pl.BlockSpec((1, tq), lambda i, j: (0, i)),
         ],
         out_specs=[
-            pl.BlockSpec((tq,), lambda i, j: (i,)),
-            pl.BlockSpec((tq, tn // 32), lambda i, j: (i, j)),
+            pl.BlockSpec((1, tq), lambda i, j: (0, i)),
+            pl.BlockSpec((tn // 32, tq), lambda i, j: (j, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nq,), jnp.int32),
-            jax.ShapeDtypeStruct((nq, nl // 32), jnp.uint32),
+            jax.ShapeDtypeStruct((1, nq), jnp.int32),
+            jax.ShapeDtypeStruct((nl // 32, nq), jnp.int32),
         ],
-        scratch_shapes=[pltpu.VMEM((tq, 1), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, tq), jnp.float32)],
         interpret=interpret,
-    )(delta, leaf_ids, qids)
+    )(delta, jnp.asarray(leaf_ids, jnp.int32)[:, None],
+      jnp.asarray(qids, jnp.int32)[None, :])
+    return cnt[0], _from_lanes(bits_t)

@@ -31,7 +31,8 @@ def _eps_count_kernel(x_ref, y_ref, mask_ref, out_ref, *, eps2: float):
     x = x_ref[...].astype(jnp.float32)  # (TQ, d)
     y = y_ref[...].astype(jnp.float32)  # (TP, d)
     acc = jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
     xs = (x * x).sum(axis=1)[:, None]
     ys = (y * y).sum(axis=1)[None, :]

@@ -1,14 +1,16 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Handles: padding to tile multiples, masking of pad rows, dtype policy,
-CPU fallback (interpret mode / pure-jnp) so the whole framework runs on this
-container while targeting TPU.
+Handles: padding to tile multiples, masking of pad rows, dtype policy, and
+the CPU test modes.
 
-`PALLAS_MODE` resolves to:
-  - "compiled"  on TPU backends
-  - "interpret" when REPRO_PALLAS=interpret (correctness validation on CPU)
-  - "jnp"       otherwise (fast CPU path via the oracles — the kernels are
-                 still the TPU codepath and are tested in interpret mode)
+``pallas_mode()`` resolves to:
+  - "compiled"  on a TPU backend, always. There ``REPRO_PALLAS`` may only be
+                unset or "compiled": an override that would route the
+                engine through the jnp oracles or the interpreter raises,
+                so nothing can hide the device path.
+  - off TPU (CPU test modes): ``REPRO_PALLAS`` = "interpret" runs the real
+    kernels through the Pallas interpreter; "jnp" (the default) runs the
+    pure-jnp oracles, which share the kernels' distance bodies.
 """
 from __future__ import annotations
 
@@ -23,11 +25,11 @@ from . import bits_epilogue as _be
 from . import ref
 from .bits_epilogue import NOCOL, SENTINEL
 from .eps_count import eps_count_pallas
-from .nng_tile import (_GBIG, _ghost_hit, _ghost_unpack, _grouped_hit,
-                       _pack_words)
+from .nng_tile import (_GBIG, _ghost_hit, _grouped_hit, _pack_words,
+                       _unpack_words)
 from .pairwise_hamming import pairwise_hamming_pallas
 from .pairwise_l2 import pairwise_sqdist_pallas
-from .tree_frontier import _frontier_masks_float, _unpack_words
+from .tree_frontier import _frontier_masks_float
 
 
 def _resolve_metric(metric):
@@ -39,9 +41,17 @@ def _resolve_metric(metric):
 
 def _mode() -> str:
     env = os.environ.get("REPRO_PALLAS", "")
-    if env in ("interpret", "jnp", "compiled"):
-        return env
-    return "compiled" if jax.default_backend() == "tpu" else "jnp"
+    if env not in ("", "interpret", "jnp", "compiled"):
+        raise ValueError(f"REPRO_PALLAS={env!r}: expected interpret, jnp "
+                         "or compiled")
+    if jax.default_backend() == "tpu":
+        if env not in ("", "compiled"):
+            raise RuntimeError(
+                f"REPRO_PALLAS={env!r} would route the engine around the "
+                "compiled kernels on a TPU backend; jnp and interpret are "
+                "CPU test modes")
+        return "compiled"
+    return env or "jnp"
 
 
 def pallas_mode() -> str:
@@ -276,8 +286,8 @@ def nng_tile_bits_grouped(
             cnt, bits = met.grouped_ref(xp, yp, xgp, ygp, xidp, yidp, eps)
         else:
             hit = _grouped_hit(
-                met.cdist(xp, yp) <= met.comparable(eps), xgp, ygp,
-                xgp >= 0, ygp >= 0, xidp, yidp)
+                met.cdist(xp, yp) <= met.comparable(eps), xgp[:, None],
+                ygp[None, :], xidp[:, None], yidp[None, :])
             cnt = jnp.sum(hit.astype(jnp.int32), axis=1)
             bits = _pack_words(hit)
     else:
@@ -306,7 +316,7 @@ def ghost_block_active(x_gbits, y_group, tq: int, tp: int):
     q = x_gbits.shape[0]
     p = y_group.shape[0]
     assert q % tq == 0 and p % tp == 0, (q, tq, p, tp)
-    xb = _ghost_unpack(x_gbits)                       # (q, m_pad) bool
+    xb = _unpack_words(x_gbits)                       # (q, m_pad) bool
     m_pad = xb.shape[1]
     xany = jnp.any(xb.reshape(q // tq, tq, m_pad), axis=1)   # (nqb, m_pad)
     yg = y_group.reshape(p // tp, tp)
@@ -357,7 +367,7 @@ def nng_tile_bits_ghost(
         else:
             hit = _ghost_hit(
                 met.cdist(xp, yp) <= met.comparable(eps),
-                _ghost_unpack(gbp), ygp, ygp >= 0)
+                _unpack_words(gbp), ygp, ygp >= 0)
             cnt = jnp.sum(hit.astype(jnp.int32), axis=1)
             bits = _pack_words(hit)
     else:
@@ -410,7 +420,8 @@ def tree_frontier_step(q, c, rad, leaf, act_bits, eps: float,
             return met.frontier_ref(q, c, rad, leaf, act_bits, eps)
         active = _unpack_words(act_bits)
         d = met.true(met.cdist(q, c))
-        emit, expand = _frontier_masks_float(d, rad, leaf, active, eps)
+        emit, expand = _frontier_masks_float(d, rad[None, :], leaf[None, :],
+                                             active, eps)
         return _pack_words(emit), _pack_words(expand)
     tq, tn = met.tile_shape(nq, N)
     qp, _ = _pad_rows(q, tq)
@@ -432,9 +443,9 @@ def tree_frontier_step(q, c, rad, leaf, act_bits, eps: float,
 # fused result epilogues (packed bitmask words -> neighbor-id tables)
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("k", "tq", "kc", "interpret"))
-def _bits_cols_padded(bits, *, k, tq, kc, interpret):
-    return _be.bits_to_cols_pallas(bits, k, tq=tq, kc=kc, interpret=interpret)
+@functools.partial(jax.jit, static_argnames=("k", "tq", "interpret"))
+def _bits_cols_padded(bits, *, k, tq, interpret):
+    return _be.bits_to_cols_pallas(bits, k, tq=tq, interpret=interpret)
 
 
 def _round_up(v: int, mult: int) -> int:
@@ -452,10 +463,9 @@ def bits_to_cols(bits, k: int) -> jnp.ndarray:
         return _be.bits_to_cols_ref(bits, k)
     m = bits.shape[0]
     tq = 128 if m >= 128 else _round_up(max(m, 1), 8)
-    kc = min(128, _round_up(k, 8))
-    kp = _round_up(k, kc)
     bp, _ = _pad_rows(bits, tq)
-    out = _bits_cols_padded(bp, k=kp, tq=tq, kc=kc,
+    bp = _pad_cols(bp, 128)          # zero words select nothing
+    out = _bits_cols_padded(bp, k=_round_up(k, 8), tq=tq,
                             interpret=mode == "interpret")
     return out[:m, :k]
 
@@ -505,12 +515,16 @@ def leaf_range_pack(delta, leaf_ids, qids):
         return _be.leaf_range_pack_ref(delta, leaf_ids, qids)
     nq = delta.shape[0]
     tq = 128 if nq >= 128 else _round_up(max(nq, 1), 8)
-    tn = next(t for t in (512, 256, 128, 64, 32) if nl % t == 0)
+    # leaf slots pad to whole 512-slot blocks (one block when smaller); pad
+    # slots carry zero deltas and SENTINEL ids, so they never cover
+    tn = 512 if nl >= 512 else _round_up(nl, 128)
     dp, _ = _pad_rows(delta, tq)
+    dp = _pad_cols(dp, tn)
+    lp, _ = _pad_rows(leaf_ids, tn, value=SENTINEL)
     qp, _ = _pad_rows(qids, tq, value=-1)
-    cnt, bits = _leaf_pack_padded(dp, leaf_ids, qp, tq=tq, tn=tn,
+    cnt, bits = _leaf_pack_padded(dp, lp, qp, tq=tq, tn=tn,
                                   interpret=mode == "interpret")
-    return cnt[:nq], bits[:nq]
+    return cnt[:nq], bits[:nq, :nl // 32]
 
 
 @jax.jit

@@ -31,7 +31,8 @@ def _sqdist_kernel(x_ref, y_ref, out_ref, *, nsteps: int):
     y = y_ref[...]  # (TP, TD)
     # MXU contraction; fp32 accumulation regardless of input dtype.
     acc = jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
     out_ref[...] += -2.0 * acc
 

@@ -6,6 +6,7 @@ over shape/dtype sweeps in ``tests/test_kernels.py``.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -22,12 +23,14 @@ def pairwise_sqdist_ref(x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
 
 
 def pairwise_sqdist_blas3_ref(x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
-    """BLAS3 expansion ||x||^2 + ||y||^2 - 2<x,y> — matches kernel math."""
+    """BLAS3 expansion ||x||^2 + ||y||^2 - 2<x,y> — matches kernel math
+    (the dot at HIGHEST precision: fp32-exact on TPU, not one bf16 pass)."""
     x = x.astype(jnp.float32)
     y = y.astype(jnp.float32)
     xn = jnp.sum(x * x, axis=-1)[:, None]
     yn = jnp.sum(y * y, axis=-1)[None, :]
-    d = xn + yn - 2.0 * (x @ y.T)
+    d = xn + yn - 2.0 * jnp.matmul(x, y.T,
+                                   precision=jax.lax.Precision.HIGHEST)
     return jnp.maximum(d, 0.0)
 
 

@@ -2,26 +2,16 @@
 
 Defined as FUNCTIONS so importing this module never touches jax device
 state (jax locks the device count on first backend init; dryrun.py must set
-XLA_FLAGS before any jax call).
-
-``AxisType`` landed in jax 0.6; on older releases every mesh axis is
-implicitly Auto, so the compat shims below simply omit the argument.
+XLA_FLAGS before any jax call). Every axis is ``AxisType.Auto``.
 """
 from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-try:
-    from jax.sharding import AxisType
-except ImportError:          # jax < 0.6: axes are implicitly Auto
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _auto_axis_kw(n_axes: int) -> dict:
-    if AxisType is None:
-        return {}
     return {"axis_types": (AxisType.Auto,) * n_axes}
 
 

@@ -82,6 +82,20 @@ def edges_from_neighbor_lists(ids, nbrs):
     return ids[ii], nbrs[ii, kk]
 
 
+def fp32_boundary_tol(pts, eps: float, metric: str) -> float:
+    """How far from eps (in true distance) a pair may sit and still be
+    classified differently by the device's fp32 arithmetic than by a
+    float64 oracle. Zero for integer (packed-bit) metrics."""
+    if pts.dtype == np.uint32:
+        return 0.0
+    if metric == "euclidean":
+        scale = float(np.max(np.abs(pts.astype(np.float64)))) ** 2
+        return 1e-5 * (scale + eps ** 2) / max(eps, 1e-9)
+    # additive float metrics (L1, user)
+    scale = float(np.max(np.abs(pts.astype(np.float64))))
+    return 1e-5 * (scale * pts.shape[1] + eps) + 1e-6
+
+
 def _run_updates(args, pts, mesh, partition):
     """``--updates`` replay: build on a prefix, stream the reserved points
     in as insert batches interleaved with random deletes, report update
@@ -175,6 +189,9 @@ def main(argv=None):
                     help="points per online insert/delete batch")
     args = ap.parse_args(argv)
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
+
     from repro.data import synthetic_pointset
     from repro.launch.mesh import make_ring_mesh
     from repro.nng import build_nng
@@ -221,14 +238,7 @@ def main(argv=None):
             diff = np.array(sorted(a ^ bset), dtype=np.int64)
             ii, jj = diff // n, diff % n
             dd = np.asarray(met.true(met.rowwise(pts[ii], pts[jj])))
-            if pts.dtype == np.uint32:
-                tol = 0.0            # integer distances: no fp32 boundary
-            elif args.metric == "euclidean":
-                scale = float(np.max(np.abs(pts.astype(np.float64)))) ** 2
-                tol = 1e-5 * (scale + args.eps ** 2) / max(args.eps, 1e-9)
-            else:                    # additive float metrics (L1, user)
-                scale = float(np.max(np.abs(pts.astype(np.float64))))
-                tol = 1e-5 * (scale * pts.shape[1] + args.eps) + 1e-6
+            tol = fp32_boundary_tol(pts, args.eps, args.metric)
             worst = float(np.max(np.abs(dd - args.eps)))
             ok = worst <= tol
             print(f"verify: {len(diff)} boundary edges, worst |d-eps|="

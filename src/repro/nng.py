@@ -145,7 +145,8 @@ class PointPartitionEngine(Engine):
             t0 = time.perf_counter()
             if forest_backend == "device":
                 forest = jax.block_until_ready(build_block_forests(
-                    self.points, mesh.size, self.metric, backend="device"))
+                    self.points, mesh.size, self.metric, backend="device",
+                    mesh=mesh))
             else:
                 forest = stack_device_forests(build_block_forests(
                     self.points, mesh.size, self.metric.host))
@@ -311,7 +312,7 @@ class SpatialPartitionEngine(Engine):
             if forest_backend == "device":
                 forest = jax.block_until_ready(build_cell_forests(
                     self.points, self.cell, self.f, nranks, self.metric,
-                    backend="device"))
+                    backend="device", mesh=mesh))
             else:
                 forest = stack_device_forests(build_cell_forests(
                     self.points, self.cell, self.f, nranks,

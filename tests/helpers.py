@@ -72,8 +72,11 @@ except ImportError:
 
 
 def run_subprocess(code: str, devices: int = 8, timeout: int = 600):
-    """Run python code in a subprocess with N host platform devices."""
+    """Run python code in a subprocess with N host platform devices. The
+    child is pinned to the CPU backend: it wants forced host devices, and
+    on a machine with a TPU the chip may be held by the parent."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run([sys.executable, "-c", code], env=env,

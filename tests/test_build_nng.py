@@ -242,6 +242,21 @@ def gap_safe_l1_eps(pts, target=3.0):
     assert d[j + 1] - d[j] > 1e-5, "no safe gap"
     return 0.5 * float(d[j] + d[j + 1])
 
+def grid_safe_l2(pts, target=1.0):
+    # Snap the points to a 2^-5 grid. With |coords| < 24 every fp32 term of
+    # the BLAS3 expansion near eps (norms, dot, the final difference) is
+    # exact, so every engine's d2 IS the float64 d2, whatever its blocking.
+    # eps2 midway between two adjacent d2 values then leaves no pair for a
+    # rounding (or a one-ulp blocking difference) to decide.
+    pts = (np.round(pts * 32) / 32).astype(np.float32)
+    assert np.abs(pts).max() < 24
+    x = pts.astype(np.float64)
+    d2 = np.unique(((x[:, None, :] - x[None, :, :]) ** 2).sum(-1))
+    k = int(np.searchsorted(d2, target ** 2))
+    eps = float(np.sqrt(0.5 * (d2[k - 1] + d2[k])))
+    assert d2[k - 1] < np.float32(eps) ** 2 < d2[k]
+    return pts, eps
+
 n = 1070                       # 1070 % 8 == 6: duplicate padding path
 cases = [("euclidean", 1.0), ("manhattan", None), ("hamming", 40)]
 for metric, eps in cases:
@@ -254,7 +269,10 @@ for metric, eps in cases:
     elif metric == "hamming":
         oracle = brute_force_graph(pts, eps, metric)   # integers: exact
     else:
-        oracle = declared_oracle(pts, eps, metric)     # fp32 declared math
+        pts, eps = grid_safe_l2(pts, eps)
+        oracle = brute_force_graph(pts, eps, metric)
+        # the declared fp32 arithmetic agrees with the float64 oracle
+        assert declared_oracle(pts, eps, metric) == oracle
     keys = []
     for partition in ("point", "spatial"):
         for traversal in ("tiles", "tree"):

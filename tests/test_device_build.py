@@ -287,3 +287,37 @@ print("DEVICE_BUILD_8DEV_OK")
 def test_build_nng_device_forests_8dev():
     out = run_subprocess(_DEVICE_BUILD_8DEV_CODE, devices=8, timeout=1200)
     assert "DEVICE_BUILD_8DEV_OK" in out
+
+
+_RING_FOREST_4DEV_CODE = r"""
+import numpy as np
+from repro.core.flat_tree import build_block_forests, build_cell_forests
+from repro.launch.mesh import make_ring_mesh
+
+mesh = make_ring_mesh(4)
+rng = np.random.default_rng(5)
+pts = rng.normal(size=(512, 8)).astype(np.float32)
+cell = rng.integers(0, 9, size=len(pts))
+f = np.arange(9) % 4
+for name, build in [
+        ("block", lambda **kw: build_block_forests(
+            pts, 4, "euclidean", backend="device", **kw)),
+        ("cell", lambda **kw: build_cell_forests(
+            pts, cell, f, 4, "euclidean", backend="device", **kw))]:
+    on_ring, on_one = build(mesh=mesh), build()
+    assert set(on_ring) == set(on_one), name
+    for k in on_one:
+        assert np.array_equal(np.asarray(on_ring[k]),
+                              np.asarray(on_one[k])), (name, k)
+        # rank r's tables live on ring device r, not all on device 0
+        assert on_ring[k].sharding.device_set == set(mesh.devices.flat), (
+            name, k, on_ring[k].sharding)
+print("RING_FOREST_4DEV_OK")
+"""
+
+
+def test_device_forests_built_on_their_ring_devices_4dev():
+    """With the engine's ring mesh, each rank's forest is built on that
+    rank's device, and the tables equal the single-device build."""
+    out = run_subprocess(_RING_FOREST_4DEV_CODE, devices=4, timeout=600)
+    assert "RING_FOREST_4DEV_OK" in out

@@ -189,6 +189,41 @@ def test_device_traversal_matches_host_flat_query():
     assert int(pruned) > 0
 
 
+@pytest.mark.parametrize("scope", ["cells", "ghost_bits"])
+def test_device_traversal_query_blocks_match_whole(monkeypatch, scope):
+    """Traversing the queries in padded blocks (``lax.map``) gives the
+    whole-batch result: same neighbor tables, counts and counters."""
+    import jax.numpy as jnp
+    from repro.core.distributed import DeviceForest, device, tree_traverse
+
+    rng = np.random.default_rng(11)
+    n = 600                                 # not a multiple of the block
+    pts = (rng.normal(size=(n, 6)) * 2).astype(np.float32)
+    cell = (rng.random(n) * 4).astype(np.int64)
+    tabs = stack_device_forests(build_cell_forests(pts, cell,
+                                                   np.zeros(4, np.int64), 1))
+    fr = DeviceForest.from_tables({k: v[0] for k, v in tabs.items()})
+    eps = safe_eps(pts, "euclidean", target_quantile=0.2)
+    args = (jnp.asarray(pts), jnp.arange(n, dtype=jnp.int32))
+    if scope == "cells":
+        kw = dict(qcells=jnp.asarray(cell, np.int32))
+    else:
+        kw = dict(qcells=None, qghost_bits=jnp.asarray(
+            rng.integers(0, 16, size=(n, 1)), jnp.uint32))
+
+    def run():
+        return [np.asarray(a) for a in tree_traverse(
+            *args, forest=fr, eps=float(eps), k_cap=256, metric="euclidean",
+            **kw)]
+
+    whole = run()
+    monkeypatch.setattr(device, "_TRAVERSE_BLOCK_ELEMS", 1)  # 128-row blocks
+    blocked = run()
+    assert whole[1].sum() > 0
+    for a, b in zip(whole, blocked):
+        assert np.array_equal(a, b)
+
+
 # ---------------------------------------------------------------------------
 # 8 simulated devices: both engines, traversal="tree", both metrics
 # ---------------------------------------------------------------------------
