@@ -1,0 +1,16 @@
+"""host_csr_ms: host milliseconds per build in the program's ``nng.csr``
+spans (``NNGraph.from_neighbor_tables``: select the pairs, sort the
+symmetric keys, count the rows), clipped to the traced window."""
+from bench.trace import clip, covered
+
+SPAN = "nng.csr"
+
+
+def read(run):
+    tr = run.trace
+    if tr is None or not run.stats:
+        return None
+    spans = [(e.start_ns, e.end_ns) for e in tr.host if e.name == SPAN]
+    if not spans:
+        return None
+    return 1e-6 * covered(clip(spans, tr.lo, tr.hi)) / len(run.stats)

@@ -512,14 +512,9 @@ def bench_systolic_device(json_path: str = "BENCH_systolic.json"):
     st, st_tree = g.stats, g_tree.stats
 
     # strong scaling over ring sizes: same workload, same steady-state
-    # timing, submeshes of the available devices. Each entry carries a
-    # comm/kernel wall-clock split: the 1-rank run has no ring traffic, so
-    # its dists/second is the pure kernel rate on this host; kernel_s_est
-    # scales each run's ACTUAL distance count by that rate and comm_s_est
-    # is the remainder (permute + dispatch + simulated-rank serialization).
+    # timing, submeshes of the available devices
     scaling = {"nranks": [], "elapsed_s": [], "edges_per_s": [],
-               "dists_evaluated": [], "skip_rate": [],
-               "kernel_s_est": [], "comm_s_est": []}
+               "dists_evaluated": [], "skip_rate": []}
     for k in sorted({r for r in (1, 2, 4, nranks) if r <= nranks}):
         gk, dtk = timed("tiles", mesh=make_nng_mesh(k), reps=2)
         assert gk == g, f"scaling mesh {k} edge mismatch"
@@ -528,21 +523,12 @@ def bench_systolic_device(json_path: str = "BENCH_systolic.json"):
         scaling["edges_per_s"].append(round(gk.num_edges / max(dtk, 1e-9), 1))
         scaling["dists_evaluated"].append(int(gk.stats.dists_evaluated))
         scaling["skip_rate"].append(round(gk.stats.tile_skip_rate, 4))
-    kernel_rate = scaling["dists_evaluated"][0] / max(
-        scaling["elapsed_s"][0], 1e-9)          # dists/s, comm-free run
-    for dists, dtk in zip(scaling["dists_evaluated"], scaling["elapsed_s"]):
-        ks = dists / max(kernel_rate, 1e-9)
-        scaling["kernel_s_est"].append(round(ks, 4))
-        scaling["comm_s_est"].append(round(max(dtk - ks, 0.0), 4))
-    # same split for the headline full-mesh run, carried on its RunStats
-    st.kernel_s_est = round(st.dists_evaluated / max(kernel_rate, 1e-9), 4)
-    st.comm_s_est = round(max(dt - st.kernel_s_est, 0.0), 4)
     # Why edges/s is NON-MONOTONE in nranks on this workload: the ring
     # schedule halves the symmetric work at every size, so total distances
     # evaluated stay ~flat from 1 -> 2 -> 4 ranks — splitting the blocks
     # does not shrink the work, it only adds per-hop dispatch, and on a
     # host-simulated mesh all "ranks" serialize onto one CPU, so elapsed
-    # grows with the overhead (comm_s_est above). Block-summary pruning
+    # grows with the overhead. Block-summary pruning
     # cannot rescue 2/4 ranks here: blocked-clusters has nranks clusters,
     # so 2- and 4-rank blocks SPAN several clusters and every block pair
     # stays within summary reach (skip_rate 0). At nranks ranks the blocks
@@ -550,8 +536,8 @@ def bench_systolic_device(json_path: str = "BENCH_systolic.json"):
     # edges/s jumps. Real multi-host meshes run ranks concurrently, which
     # removes the serialization term but not the flat-work term.
     scaling_note = ("edges/s dips at 2/4 ranks: symmetric-halving keeps "
-                    "total distance work ~flat while per-hop overhead grows "
-                    "(see comm_s_est); block-summary pruning only fires "
+                    "total distance work ~flat while per-hop overhead grows; "
+                    "block-summary pruning only fires "
                     "once blocks align with the data's clusters at "
                     f"{nranks} ranks — see skip_rate per entry")
 
@@ -561,8 +547,6 @@ def bench_systolic_device(json_path: str = "BENCH_systolic.json"):
         "pallas_mode": pallas_mode(),
         "edges": g.num_edges,
         "elapsed_s": round(dt, 4),
-        "kernel_s_est": st.kernel_s_est,
-        "comm_s_est": st.comm_s_est,
         # forest-construction wall clock (warm device build, the backend
         # the tree path above actually ran with), SEPARATE from elapsed_s
         "build_s": forest_ab["device_s"],

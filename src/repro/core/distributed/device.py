@@ -88,11 +88,12 @@ from repro.kernels import (nng_tile_bits, nng_tile_bits_ghost,
                            nng_tile_geometry, tree_frontier_step)
 from repro.kernels.nng_tile import _pack_words, _unpack_words
 from repro.kernels.ops import pallas_mode as _pallas_mode
+from repro.obs import span
 # fused bitmask→ids epilogues (repro.kernels.bits_epilogue): rank-selection
 # over word popcounts in VMEM replaces the old two-pass ``lax.top_k``
 # extraction — same contract (k smallest hit columns/ids, ascending,
 # padded), bit-identical output, no dense candidate array
-from repro.kernels.ops import (bits_to_ids as _bits_to_ids,
+from repro.kernels.ops import (bits_to_ids as _bits_to_ids_op,
                                bits_to_gathered_ids as _bits_to_gathered_ids,
                                leaf_range_pack as _leaf_range_pack)
 
@@ -143,12 +144,31 @@ def tile_cdist(x, y, metric):
     return get_metric(metric).cdist(x, y)
 
 
+# The systolic bodies name their stages with ``jax.named_scope`` ("nng.tile",
+# "nng.traverse", "nng.epilogue", "nng.merge", "nng.ring",
+# "nng.mirror_home"): the names land in each operation's ``op_name``
+# metadata, which a profile viewer shows, and rename no HLO instruction —
+# the kernels' custom calls keep the names of their jitted wrappers.
+
 def _merge_ids(buf, new_ids):
     """Merge two per-row sorted id sets, keeping the K smallest (dedup-free:
     ids are globally unique per source)."""
-    k = buf.shape[-1]
-    cat = jnp.concatenate([buf, new_ids], axis=-1)
-    return jnp.sort(cat, axis=-1)[..., :k]
+    with jax.named_scope("nng.merge"):
+        k = buf.shape[-1]
+        cat = jnp.concatenate([buf, new_ids], axis=-1)
+        return jnp.sort(cat, axis=-1)[..., :k]
+
+
+def _bits_to_ids(bits, id0, k_cap):
+    """The bitmask epilogue: hit words -> (m, k_cap) ids from ``id0``."""
+    with jax.named_scope("nng.epilogue"):
+        return _bits_to_ids_op(bits, id0, k_cap)
+
+
+def _ring_permute(arrays, axis, perm, scope="nng.ring"):
+    """One ``ppermute`` of each array, under the ring's scope."""
+    with jax.named_scope(scope):
+        return tuple(jax.lax.ppermute(a, axis, perm) for a in arrays)
 
 
 
@@ -359,7 +379,8 @@ def _systolic_local(x, ids, *, axis, nranks, eps, metric, k_cap, prune,
     ones = jnp.ones((n_loc,), jnp.int32)
 
     def tile_bits(a, b):
-        return nng_tile_bits(a, b, ones, eps, metric=metric)
+        with jax.named_scope("nng.tile"):
+            return nng_tile_bits(a, b, ones, eps, metric=metric)
 
     # the WHOLE tile evaluation — kernel, id extraction, merge — sits
     # inside a cond so a pruned round costs only the permutes
@@ -376,10 +397,8 @@ def _systolic_local(x, ids, *, axis, nranks, eps, metric, k_cap, prune,
     def step_serial(r, carry):
         # strict rotate-then-evaluate: round r's tile waits on round r's hop
         y, yid0, ynbrs, ycnt, nbrs, cnt = carry
-        y = jax.lax.ppermute(y, axis, perm)
-        yid0 = jax.lax.ppermute(yid0, axis, perm)
-        ynbrs = jax.lax.ppermute(ynbrs, axis, perm)
-        ycnt = jax.lax.ppermute(ycnt, axis, perm)
+        y, yid0, ynbrs, ycnt = _ring_permute((y, yid0, ynbrs, ycnt), axis,
+                                             perm)
         nbrs, cnt, ynbrs, ycnt = jax.lax.cond(
             do_eval[r], lambda acc: _eval_pair(y, yid0, acc),
             lambda acc: acc, (nbrs, cnt, ynbrs, ycnt))
@@ -390,12 +409,10 @@ def _systolic_local(x, ids, *, axis, nranks, eps, metric, k_cap, prune,
         # iteration / pre-loop); issue hop r+1 first, then evaluate round r
         # — permute and kernels are dependency-free, so they overlap
         y, yid0, ynbrs, ycnt, nbrs, cnt = carry
-        y_next = jax.lax.ppermute(y, axis, perm)
-        yid_next = jax.lax.ppermute(yid0, axis, perm)
+        y_next, yid_next = _ring_permute((y, yid0), axis, perm)
         # mirror accumulator rides one hop behind the block: permuted here,
         # merged by this round's eval (also overlaps the kernels)
-        ynbrs = jax.lax.ppermute(ynbrs, axis, perm)
-        ycnt = jax.lax.ppermute(ycnt, axis, perm)
+        ynbrs, ycnt = _ring_permute((ynbrs, ycnt), axis, perm)
         nbrs, cnt, ynbrs, ycnt = jax.lax.cond(
             do_eval[r], lambda acc: _eval_pair(y, yid0, acc),
             lambda acc: acc, (nbrs, cnt, ynbrs, ycnt))
@@ -405,8 +422,7 @@ def _systolic_local(x, ids, *, axis, nranks, eps, metric, k_cap, prune,
     cnt0 = jnp.zeros((n_loc,), dtype=jnp.int32)
     if overlap and rounds > 0:
         # prime the pipeline: hop 1 in flight while the self tile runs below
-        y1 = jax.lax.ppermute(x, axis, perm)
-        yid1 = jax.lax.ppermute(id0, axis, perm)
+        y1, yid1 = _ring_permute((x, id0), axis, perm)
     # self tile (round 0): clear the diagonal bit (row i, column i) and take
     # counts from the cleared bitmask — structurally excludes self pairs
     # even when fp32 rounding pushes d(x, x) past eps.
@@ -429,8 +445,8 @@ def _systolic_local(x, ids, *, axis, nranks, eps, metric, k_cap, prune,
         # each block's mirror accumulator sits `rounds` hops downstream of
         # its home rank; one permute returns it
         perm_home = [(i, (i + rounds) % nranks) for i in range(nranks)]
-        ynbrs = jax.lax.ppermute(ynbrs, axis, perm_home)
-        ycnt = jax.lax.ppermute(ycnt, axis, perm_home)
+        ynbrs, ycnt = _ring_permute((ynbrs, ycnt), axis, perm_home,
+                                    "nng.mirror_home")
         nbrs = _merge_ids(nbrs, ynbrs)
         cnt = cnt + ycnt
     overflow = jnp.any(cnt > k_cap)[None]
@@ -478,16 +494,14 @@ def _systolic_local_tree(x, ids, *forest_arrays, axis, nranks, eps, metric,
     tiles_skipped = jnp.sum((sched & skip).astype(jnp.float32))
 
     def trav(qp, qids, fr):
-        return tree_traverse(qp, qids, qcells, fr, eps, k_cap, metric)
+        with jax.named_scope("nng.traverse"):
+            return tree_traverse(qp, qids, qcells, fr, eps, k_cap, metric)
 
     def step(r, carry):
         y, yids, yforest, ynbrs, ycnt, nbrs, cnt, dists, pruned = carry
-        y = jax.lax.ppermute(y, axis, perm)
-        yids = jax.lax.ppermute(yids, axis, perm)
-        yforest = jax.tree.map(
-            lambda a: jax.lax.ppermute(a, axis, perm), yforest)
-        ynbrs = jax.lax.ppermute(ynbrs, axis, perm)
-        ycnt = jax.lax.ppermute(ycnt, axis, perm)
+        y, yids = _ring_permute((y, yids), axis, perm)
+        yforest = DeviceForest(*_ring_permute(yforest, axis, perm))
+        ynbrs, ycnt = _ring_permute((ynbrs, ycnt), axis, perm)
 
         def _eval(acc):
             nbrs_, cnt_, ynbrs_, ycnt_, d_, p_ = acc
@@ -512,8 +526,8 @@ def _systolic_local_tree(x, ids, *forest_arrays, axis, nranks, eps, metric,
             1, rounds + 1, step,
             (x, ids, forest, nbrs0, cnt0, nbrs, cnt, dists, pruned))
         perm_home = [(i, (i + rounds) % nranks) for i in range(nranks)]
-        ynbrs = jax.lax.ppermute(ynbrs, axis, perm_home)
-        ycnt = jax.lax.ppermute(ycnt, axis, perm_home)
+        ynbrs, ycnt = _ring_permute((ynbrs, ycnt), axis, perm_home,
+                                    "nng.mirror_home")
         nbrs = _merge_ids(nbrs, ynbrs)
         cnt = cnt + ycnt
     overflow = jnp.any(cnt > k_cap)[None]
@@ -575,10 +589,11 @@ def _systolic_local_tree_split(x, ids, *forest_arrays, axis, nranks, eps,
     tiles_skipped = jnp.sum((sched & skip).astype(jnp.float32))
 
     def trav(qp, qids, fr):
-        return tree_traverse(qp, qids, qcells, fr, eps, k_cap, metric)
+        with jax.named_scope("nng.traverse"):
+            return tree_traverse(qp, qids, qcells, fr, eps, k_cap, metric)
 
     def rot(a):
-        return jax.lax.ppermute(a, axis, perm)
+        return _ring_permute((a,), axis, perm)[0]
 
     nbrs0 = jnp.full((n_loc, k_cap), SENTINEL, dtype=jnp.int32)
     cnt0 = jnp.zeros((n_loc,), dtype=jnp.int32)
@@ -606,8 +621,8 @@ def _systolic_local_tree_split(x, ids, *forest_arrays, axis, nranks, eps,
                 # to round r+1 — one collective, one hop's bytes
                 jump = (r + 1) - vpos
                 pjump = [(i, (i - jump) % nranks) for i in range(nranks)]
-                vforest = jax.tree.map(
-                    lambda a: jax.lax.ppermute(a, axis, pjump), vforest)
+                vforest = DeviceForest(*_ring_permute(vforest, axis,
+                                                      pjump))
                 vpos = r + 1
         # mirror accumulator: one hop behind the block, merged by this
         # round's eval — its permute overlaps the kernels too
@@ -625,8 +640,9 @@ def _systolic_local_tree_split(x, ids, *forest_arrays, axis, nranks, eps,
         else:
             def _eval(acc):
                 nbrs_, cnt_, ynbrs_, ycnt_, d_, p_ = acc
-                fc, fb, rc, rb = nng_tile_bits_pair(x, y_cur, eps,
-                                                    metric=metric)
+                with jax.named_scope("nng.tile"):
+                    fc, fb, rc, rb = nng_tile_bits_pair(x, y_cur, eps,
+                                                        metric=metric)
                 nbrs_ = _merge_ids(nbrs_, _bits_to_ids(fb, yids_cur[0],
                                                        k_cap))
                 ynbrs_ = _merge_ids(ynbrs_, _bits_to_ids(rb, id0, k_cap))
@@ -638,8 +654,8 @@ def _systolic_local_tree_split(x, ids, *forest_arrays, axis, nranks, eps,
 
     if rounds > 0:
         perm_home = [(i, (i + rounds) % nranks) for i in range(nranks)]
-        ynbrs = jax.lax.ppermute(ynbrs, axis, perm_home)
-        ycnt = jax.lax.ppermute(ycnt, axis, perm_home)
+        ynbrs, ycnt = _ring_permute((ynbrs, ycnt), axis, perm_home,
+                                    "nng.mirror_home")
         nbrs = _merge_ids(nbrs, ynbrs)
         cnt = cnt + ycnt
     overflow = jnp.any(cnt > k_cap)[None]
@@ -825,13 +841,15 @@ def systolic_run(
     fn = _systolic_fn(mesh, float(eps), met, k_cap, axis, prune,
                       _pallas_mode(), traversal, overlap, ring_modes,
                       forest_backend)
-    points = _on_ring(mesh, axis, points, met.dtype)
-    ids = _on_ring(mesh, axis, ids)
     if traversal == "tree":
         assert forest is not None, "traversal='tree' needs stacked forests"
-        ftabs = [_on_ring(mesh, axis, forest[k]) for k in DeviceForest._fields]
-        return fn(points, ids, *ftabs)
-    return fn(points, ids)
+    with span("nng.put"):
+        points = _on_ring(mesh, axis, points, met.dtype)
+        ids = _on_ring(mesh, axis, ids)
+        ftabs = ([_on_ring(mesh, axis, forest[k])
+                  for k in DeviceForest._fields]
+                 if traversal == "tree" else [])
+    return fn(points, ids, *ftabs)
 
 
 def systolic_nng(points, eps, mesh, **kw):
@@ -1426,7 +1444,6 @@ def landmark_run(
     nranks = mesh.shape[axis]
     n, _ = points.shape
     assert n % nranks == 0, (n, nranks)
-    ids = _on_ring(mesh, axis, np.arange(n, dtype=np.int32))
     assert ghost_mode in ("coll", "ring"), (
         f"ghost_mode={ghost_mode!r}: 'auto' is resolved upstream "
         "(resolve_ghost_mode) — the engine compiles one mode")
@@ -1436,17 +1453,20 @@ def landmark_run(
             "plan_landmark_device, or set cap_rank explicitly)")
     fn = _landmark_fn(mesh, float(eps), met, plan, axis, _pallas_mode(),
                       traversal, forest_backend, ghost_mode)
-    points = _on_ring(mesh, axis, points, met.dtype)
-    centers = jnp.asarray(centers, met.dtype)
-    f = jnp.asarray(f, jnp.int32)
     if traversal == "tree":
         assert forest is not None, "traversal='tree' needs stacked forests"
         assert cell is not None, ("traversal='tree' needs the cell "
                                   "assignment the forests were built from")
-        ftabs = [_on_ring(mesh, axis, forest[k]) for k in DeviceForest._fields]
-        return fn(points, ids, centers, f,
-                  _on_ring(mesh, axis, cell, np.int32), *ftabs)
-    return fn(points, ids, centers, f)
+    with span("nng.put"):
+        ids = _on_ring(mesh, axis, np.arange(n, dtype=np.int32))
+        points = _on_ring(mesh, axis, points, met.dtype)
+        centers = jnp.asarray(centers, met.dtype)
+        f = jnp.asarray(f, jnp.int32)
+        tree_args = ([_on_ring(mesh, axis, cell, np.int32)]
+                     + [_on_ring(mesh, axis, forest[k])
+                        for k in DeviceForest._fields]
+                     if traversal == "tree" else [])
+    return fn(points, ids, centers, f, *tree_args)
 
 
 def landmark_nng(points, eps, centers, f, mesh, plan, **kw):

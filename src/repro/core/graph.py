@@ -18,6 +18,8 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from repro.obs import count, span
+
 SENTINEL = 2**31 - 1     # neighbor-table padding id (device.SENTINEL)
 
 
@@ -39,20 +41,28 @@ class RunStats:
     comm_bytes: dict = field(default_factory=dict)  # channel -> bytes
     overflow: bool = False         # final run overflowed (never via drivers)
     replans: int = 0               # overflow -> grow iterations taken
-    elapsed_s: float = 0.0         # wall clock of the final (exact) run
-    build_s: float = 0.0           # forest-construction wall clock (tree
-                                   # traversal only; 0.0 on tile paths —
-                                   # reported SEPARATELY from elapsed_s)
-    kernel_s_est: float = 0.0      # est. wall clock inside distance kernels
-                                   # (dists_evaluated / microbenched pair
-                                   # throughput; 0.0 when not estimated)
-    comm_s_est: float = 0.0        # elapsed_s - kernel_s_est when estimated:
-                                   # collectives + dispatch + epilogues
+    elapsed_s: float = 0.0         # wall clock of the final (exact) run:
+                                   # the nng.rerun span
+    build_s: float = 0.0           # forest-construction wall clock, the
+                                   # nng.forest span (tree traversal only;
+                                   # 0.0 on tile paths — reported
+                                   # SEPARATELY from elapsed_s)
     update_s: float = 0.0          # wall clock spent in online updates
                                    # (OnlineNNG insert/delete, cumulative —
                                    # separate from the batch elapsed_s)
     edges_added: float = 0.0       # undirected edges appended by updates
     edges_removed: float = 0.0     # undirected edges dropped by tombstones
+    # host side of one build (repro.obs): filled where the work happens
+    engine_calls: int = 0          # engine invocations: warm runs (one per
+                                   # grow too) plus the steady re-run
+    compiles: int = 0              # backend compiles (persistent-cache
+    compile_s: float = 0.0         # loads included) and their seconds
+    fetch_bytes: int = 0           # bytes of the tables copied to the host
+    table_slots: int = 0           # rows x width of the neighbour tables
+    pairs_selected: int = 0        # their non-SENTINEL entries of valid
+                                   # rows: directed pairs before symmetry
+    spans: list = field(default_factory=list)  # (name, parent, start_s,
+                                               # end_s), perf_counter clock
 
     @property
     def total_comm_bytes(self) -> float:
@@ -227,16 +237,18 @@ class NNGraph:
         """Build from directed (src, dst) hit pairs: drops self loops and
         out-of-range endpoints (driver padding rows), symmetrizes, dedups.
         """
-        src = np.asarray(src, np.int64)
-        dst = np.asarray(dst, np.int64)
-        keep = (src < n) & (dst < n) & (src >= 0) & (dst >= 0) & (src != dst)
-        src, dst = src[keep], dst[keep]
-        key = np.unique(np.concatenate([src * n + dst, dst * n + src]))
-        rows = key // n
-        cols = key % n
-        row_ptr = np.zeros(n + 1, np.int64)
-        np.cumsum(np.bincount(rows, minlength=n), out=row_ptr[1:])
-        return cls(n, row_ptr, cols.astype(np.int32), stats, meta)
+        with span("nng.csr.sort"):
+            src = np.asarray(src, np.int64)
+            dst = np.asarray(dst, np.int64)
+            keep = ((src < n) & (dst < n) & (src >= 0) & (dst >= 0)
+                    & (src != dst))
+            src, dst = src[keep], dst[keep]
+            key = np.unique(np.concatenate([src * n + dst, dst * n + src]))
+        with span("nng.csr.rows"):
+            row_ptr = np.zeros(n + 1, np.int64)
+            np.cumsum(np.bincount(key // n, minlength=n), out=row_ptr[1:])
+            cols = (key % n).astype(np.int32)
+        return cls(n, row_ptr, cols, stats, meta)
 
     @classmethod
     def from_neighbor_tables(cls, n: int, tables, stats=None, meta=None
@@ -246,15 +258,20 @@ class NNGraph:
         (one per engine phase — e.g. owned + ghost for the landmark
         engine). Rows with id >= n (duplicate-padding) are dropped."""
         src_all, dst_all = [], []
-        for ids, nbrs in tables:
-            ids = np.asarray(ids)
-            nbrs = np.asarray(nbrs)
-            valid = (ids != SENTINEL) & (ids < n)
-            ii, kk = np.nonzero((nbrs != SENTINEL) & valid[:, None])
-            src_all.append(ids[ii])
-            dst_all.append(nbrs[ii, kk])
-        src = np.concatenate(src_all) if src_all else np.zeros(0, np.int64)
-        dst = np.concatenate(dst_all) if dst_all else np.zeros(0, np.int64)
+        with span("nng.csr.select"):
+            for ids, nbrs in tables:
+                ids = np.asarray(ids)
+                nbrs = np.asarray(nbrs)
+                valid = (ids != SENTINEL) & (ids < n)
+                ii, kk = np.nonzero((nbrs != SENTINEL) & valid[:, None])
+                src_all.append(ids[ii])
+                dst_all.append(nbrs[ii, kk])
+                count("table_slots", nbrs.size)
+                count("pairs_selected", len(ii))
+            src = (np.concatenate(src_all) if src_all
+                   else np.zeros(0, np.int64))
+            dst = (np.concatenate(dst_all) if dst_all
+                   else np.zeros(0, np.int64))
         return cls.from_directed_pairs(n, src, dst, stats, meta)
 
     # -- accessors ----------------------------------------------------------

@@ -195,6 +195,7 @@ def main(argv=None):
     from repro.data import synthetic_pointset
     from repro.launch.mesh import make_ring_mesh
     from repro.nng import build_nng
+    from repro.obs import totals
 
     mesh = make_ring_mesh()
     partition = "point" if args.algo == "systolic" else "spatial"
@@ -220,6 +221,11 @@ def main(argv=None):
           f"nodes_pruned={st.nodes_pruned:.0f} "
           f"comm_bytes={st.total_comm_bytes:.0f} replans={st.replans}")
     print(f"{g} in {st.elapsed_s:.2f}s (plan={g.meta['plan']})")
+    print(f"engine_calls={st.engine_calls} compiles={st.compiles} "
+          f"({st.compile_s:.2f}s) fetch_bytes={st.fetch_bytes} table_fill="
+          f"{100 * st.pairs_selected / max(st.table_slots, 1):.2f}%")
+    for name, secs in totals(st.spans).items():
+        print(f"  {name:<15} {secs:.4f}s")
 
     if args.verify:
         from repro.core.brute import brute_force_graph

@@ -30,8 +30,6 @@ dropped when the CSR is assembled — exactness is preserved for ANY metric
 """
 from __future__ import annotations
 
-import time
-
 import jax
 import numpy as np
 
@@ -44,6 +42,7 @@ from repro.core.distributed import (LandmarkPlan, delta_bcast_bytes,
 from repro.core.graph import NNGraph, RunStats, SENTINEL
 from repro.core.landmark import ghost_membership, lpt_assignment, select_centers
 from repro.core.metrics import Metric, get_metric, register_metric  # noqa: F401 (re-export)
+from repro.obs import count, recording, span
 
 __all__ = ["build_nng", "delta_run", "drive", "DeltaEngine", "Engine",
            "PointPartitionEngine", "SpatialPartitionEngine", "grow_plan",
@@ -78,11 +77,27 @@ class Engine:
         raise NotImplementedError
 
     def neighbor_tables(self, out):
-        """[(ids, nbrs), ...] SENTINEL-padded tables for CSR assembly."""
+        """[(ids, nbrs), ...] SENTINEL-padded tables for CSR assembly,
+        copied to the host by ``_fetch``."""
         raise NotImplementedError
 
     def run_stats(self, out, plan) -> RunStats:
         raise NotImplementedError
+
+
+def _run_engine(engine: Engine, plan):
+    """One engine invocation, finished on the device."""
+    count("engine_calls")
+    out = engine.run(plan)
+    with span("nng.wait"):
+        return jax.block_until_ready(out)
+
+
+def _fetch(*arrays) -> list[np.ndarray]:
+    """Copy engine outputs to the host, counting the bytes."""
+    host = [np.asarray(a) for a in arrays]
+    count("fetch_bytes", sum(a.nbytes for a in host))
+    return host
 
 
 def drive(engine: Engine, max_grows: int = 8, *, steady_state: bool = True):
@@ -94,24 +109,28 @@ def drive(engine: Engine, max_grows: int = 8, *, steady_state: bool = True):
     a static capacity knob, so the winning run is always a freshly traced +
     compiled program — its first invocation conflates compile with
     execution. The winner is therefore invoked a second time (a jit cache
-    hit) and THAT wall clock is reported: ``RunStats.elapsed_s`` and both
-    bench JSONs measure engine execution, never compilation.
+    hit, the ``nng.rerun`` span) and THAT wall clock is reported:
+    ``RunStats.elapsed_s`` and both bench JSONs measure engine execution,
+    never compilation.
 
     ``steady_state=False`` skips the timing re-run and reports the warm
-    (compile-inclusive) wall clock — for callers that only consume the
-    neighbor tables, where doubling the winning run buys nothing."""
-    plan = engine.initial_plan()
+    (compile-inclusive) ``nng.run`` wall clock — for callers that only
+    consume the neighbor tables, where doubling the winning run buys
+    nothing."""
+    with span("nng.plan"):
+        plan = engine.initial_plan()
     for attempt in range(max_grows):
-        t0 = time.perf_counter()
-        out = jax.block_until_ready(engine.run(plan))  # warm: trace+compile
-        elapsed = time.perf_counter() - t0
-        if not engine.overflowed(out):
+        with span("nng.run") as timed:      # warm: trace + compile
+            out = _run_engine(engine, plan)
+            with span("nng.check"):
+                overflowed = engine.overflowed(out)
+        if not overflowed:
             if steady_state:
-                t0 = time.perf_counter()
-                out = jax.block_until_ready(engine.run(plan))
-                elapsed = time.perf_counter() - t0
-            return out, plan, attempt, elapsed
-        plan = engine.grow(plan, out)
+                with span("nng.rerun") as timed:
+                    out = _run_engine(engine, plan)
+            return out, plan, attempt, timed.seconds
+        with span("nng.grow"):
+            plan = engine.grow(plan, out)
     raise RuntimeError(
         f"{engine.name} engine: overflow persists after {max_grows} grows "
         f"(last plan: {plan})")
@@ -142,15 +161,15 @@ class PointPartitionEngine(Engine):
         if traversal == "tree" and forest is None:
             from repro.core.flat_tree import (build_block_forests,
                                               stack_device_forests)
-            t0 = time.perf_counter()
-            if forest_backend == "device":
-                forest = jax.block_until_ready(build_block_forests(
-                    self.points, mesh.size, self.metric, backend="device",
-                    mesh=mesh))
-            else:
-                forest = stack_device_forests(build_block_forests(
-                    self.points, mesh.size, self.metric.host))
-            self.build_s = time.perf_counter() - t0
+            with span("nng.forest") as timed:
+                if forest_backend == "device":
+                    forest = jax.block_until_ready(build_block_forests(
+                        self.points, mesh.size, self.metric,
+                        backend="device", mesh=mesh))
+                else:
+                    forest = stack_device_forests(build_block_forests(
+                        self.points, mesh.size, self.metric.host))
+            self.build_s = timed.seconds
         self.forest = forest
         # the split ring schedule is static (part of the compiled program),
         # so plan it once per engine — the grow loop only changes k_cap
@@ -179,7 +198,7 @@ class PointPartitionEngine(Engine):
         return max(2 * k_cap, int(np.asarray(out[1]).max()))
 
     def neighbor_tables(self, out):
-        nbrs = np.asarray(out[0])
+        [nbrs] = _fetch(out[0])
         return [(np.arange(len(nbrs), dtype=np.int64), nbrs)]
 
     def _ring_comm_bytes(self, k_cap: int) -> dict:
@@ -308,16 +327,16 @@ class SpatialPartitionEngine(Engine):
         if traversal == "tree" and forest is None:
             from repro.core.flat_tree import (build_cell_forests,
                                               stack_device_forests)
-            t0 = time.perf_counter()
-            if forest_backend == "device":
-                forest = jax.block_until_ready(build_cell_forests(
-                    self.points, self.cell, self.f, nranks, self.metric,
-                    backend="device", mesh=mesh))
-            else:
-                forest = stack_device_forests(build_cell_forests(
-                    self.points, self.cell, self.f, nranks,
-                    self.metric.host))
-            self.build_s = time.perf_counter() - t0
+            with span("nng.forest") as timed:
+                if forest_backend == "device":
+                    forest = jax.block_until_ready(build_cell_forests(
+                        self.points, self.cell, self.f, nranks, self.metric,
+                        backend="device", mesh=mesh))
+                else:
+                    forest = stack_device_forests(build_cell_forests(
+                        self.points, self.cell, self.f, nranks,
+                        self.metric.host))
+            self.build_s = timed.seconds
         self.forest = forest
 
     # -- planning -----------------------------------------------------------
@@ -392,8 +411,8 @@ class SpatialPartitionEngine(Engine):
         return grow_plan(plan)
 
     def neighbor_tables(self, out):
-        return [(np.asarray(out[0]), np.asarray(out[1])),
-                (np.asarray(out[3]), np.asarray(out[4]))]
+        ids, nbrs, gids, gnbrs = _fetch(out[0], out[1], out[3], out[4])
+        return [(ids, nbrs), (gids, gnbrs)]
 
     def _landmark_comm_bytes(self, plan: LandmarkPlan) -> dict:
         """Per-channel exchange bytes. ``coalesce`` moves three
@@ -483,7 +502,8 @@ class DeltaEngine(Engine):
 
     def neighbor_tables(self, out):
         nranks = self.mesh.shape[self.axis]
-        return [(np.tile(self.qids, nranks), np.asarray(out[0]))]
+        [nbrs] = _fetch(out[0])
+        return [(np.tile(self.qids, nranks), nbrs)]
 
     def run_stats(self, out, k_cap) -> RunStats:
         nranks = self.mesh.shape[self.axis]
@@ -507,14 +527,18 @@ def delta_run(batch_points, batch_ids, forest: dict, eps, mesh, *,
     ``RunStats``. Symmetrize downstream (``NNGraph.delta_add_edges``
     canonicalizes) — a batch-internal pair appears from both endpoints.
     """
-    engine = DeltaEngine(batch_points, batch_ids, forest, eps, mesh, metric,
-                         k_cap=k_cap, axis=axis)
-    out, plan, replans, elapsed = drive(engine, max_grows=max_grows,
-                                        steady_state=False)
-    stats = engine.run_stats(out, plan)
-    stats.replans = replans
-    stats.elapsed_s = elapsed
-    [(ids, nbrs)] = engine.neighbor_tables(out)
+    with recording() as rec:
+        engine = DeltaEngine(batch_points, batch_ids, forest, eps, mesh,
+                             metric, k_cap=k_cap, axis=axis)
+        out, plan, replans, elapsed = drive(engine, max_grows=max_grows,
+                                            steady_state=False)
+        with span("nng.stats"):
+            stats = engine.run_stats(out, plan)
+        stats.replans = replans
+        stats.elapsed_s = elapsed
+        with span("nng.fetch"):
+            [(ids, nbrs)] = engine.neighbor_tables(out)
+    rec.into(stats)
     valid = ids != SENTINEL
     ii, kk = np.nonzero((nbrs != SENTINEL) & valid[:, None])
     return ids[ii], nbrs[ii, kk].astype(np.int64), stats
@@ -560,58 +584,72 @@ def build_nng(
     padded all_to_all, the default), ``"ring"`` (ghost-free block
     rotation), or ``"auto"`` (per-plan pick from the exact byte models —
     the resolved choice lands in ``meta["ghost_mode"]``).
+
+    ``g.stats`` also holds the host side of the build: its ``nng.*`` spans
+    (``repro.obs``) and the counters ``engine_calls``, ``compiles``,
+    ``compile_s``, ``fetch_bytes``, ``table_slots`` and
+    ``pairs_selected``.
     """
-    met = get_metric(metric)
-    if mesh is None:
-        mesh = make_nng_mesh()
-    points = np.ascontiguousarray(np.asarray(points, met.host.dtype))
-    n = len(points)
-    if n == 0:
-        return NNGraph(0, np.zeros(1, np.int64), np.zeros(0, np.int32),
-                       meta={"metric": met.name, "eps": float(eps)})
-    pad = (-n) % mesh.size
-    if pad:
-        # duplicate-pad by cycling the input (np.resize) — works even when
-        # pad > n (tiny point sets on wide meshes)
-        run_points = np.concatenate(
-            [points, np.resize(points, (pad,) + points.shape[1:])])
-    else:
-        run_points = points
+    with recording() as rec:
+        with span("nng.prepare"):
+            met = get_metric(metric)
+            if mesh is None:
+                mesh = make_nng_mesh()
+            points = np.ascontiguousarray(np.asarray(points, met.host.dtype))
+            n = len(points)
+            if n == 0:
+                return NNGraph(0, np.zeros(1, np.int64), np.zeros(0, np.int32),
+                               meta={"metric": met.name, "eps": float(eps)})
+            pad = (-n) % mesh.size
+            if pad:
+                # duplicate-pad by cycling the input (np.resize) — works even
+                # when pad > n (tiny point sets on wide meshes)
+                run_points = np.concatenate(
+                    [points, np.resize(points, (pad,) + points.shape[1:])])
+            else:
+                run_points = points
 
-    if partition == "point":
-        engine = PointPartitionEngine(
-            run_points, eps, mesh, met, k_cap=k_cap or 64, prune=prune,
-            traversal=traversal, overlap=overlap,
-            forest_backend=forest_backend)
-    elif partition == "spatial":
-        engine = SpatialPartitionEngine(
-            run_points, eps, mesh, met, k_cap=k_cap or 128, planner=planner,
-            m_centers=m_centers, traversal=traversal, seed=seed,
-            forest_backend=forest_backend, ghost_mode=ghost_mode)
-    else:
-        raise ValueError(
-            f"unknown partition {partition!r} (want 'point' or 'spatial')")
+            if partition == "point":
+                engine = PointPartitionEngine(
+                    run_points, eps, mesh, met, k_cap=k_cap or 64, prune=prune,
+                    traversal=traversal, overlap=overlap,
+                    forest_backend=forest_backend)
+            elif partition == "spatial":
+                engine = SpatialPartitionEngine(
+                    run_points, eps, mesh, met, k_cap=k_cap or 128,
+                    planner=planner, m_centers=m_centers, traversal=traversal,
+                    seed=seed, forest_backend=forest_backend,
+                    ghost_mode=ghost_mode)
+            else:
+                raise ValueError(
+                    f"unknown partition {partition!r} (want 'point' or "
+                    "'spatial')")
 
-    out, plan, replans, elapsed = drive(engine, max_grows=max_grows)
-    stats = engine.run_stats(out, plan)
-    stats.replans = replans
-    stats.elapsed_s = elapsed
-    stats.build_s = engine.build_s
-    meta = {
-        "metric": met.name, "eps": float(eps), "partition": partition,
-        "traversal": traversal, "nranks": mesh.size, "padded": pad,
-        "plan": plan,
-    }
-    if traversal == "tree":
-        meta["forest_backend"] = forest_backend
-    if partition == "point":
-        meta["overlap"] = bool(overlap)
-        if engine.ring_schedule is not None:
-            meta["ring_schedule"] = tuple(engine.ring_schedule)
-    if partition == "spatial":
-        meta["planner"] = planner
-        meta["m_centers"] = engine.m_centers
-        # the RESOLVED mode, never "auto" — what the final plan compiled
-        meta["ghost_mode"] = engine.resolved_ghost_mode(plan)
-    return NNGraph.from_neighbor_tables(
-        n, engine.neighbor_tables(out), stats=stats, meta=meta)
+        out, plan, replans, elapsed = drive(engine, max_grows=max_grows)
+        with span("nng.stats"):
+            stats = engine.run_stats(out, plan)
+        stats.replans = replans
+        stats.elapsed_s = elapsed
+        stats.build_s = engine.build_s
+        meta = {
+            "metric": met.name, "eps": float(eps), "partition": partition,
+            "traversal": traversal, "nranks": mesh.size, "padded": pad,
+            "plan": plan,
+        }
+        if traversal == "tree":
+            meta["forest_backend"] = forest_backend
+        if partition == "point":
+            meta["overlap"] = bool(overlap)
+            if engine.ring_schedule is not None:
+                meta["ring_schedule"] = tuple(engine.ring_schedule)
+        if partition == "spatial":
+            meta["planner"] = planner
+            meta["m_centers"] = engine.m_centers
+            # the RESOLVED mode, never "auto" — what the final plan compiled
+            meta["ghost_mode"] = engine.resolved_ghost_mode(plan)
+        with span("nng.fetch"):
+            tables = engine.neighbor_tables(out)
+        with span("nng.csr"):
+            g = NNGraph.from_neighbor_tables(n, tables, stats=stats, meta=meta)
+        rec.into(stats)
+    return g

@@ -151,3 +151,36 @@ def test_systolic_tiles_program_compiles(topo, one_chip, compiled_mode):
     compiled = _compile(fn, one_chip, ((n, 128), f32), ((n,), i32))
     mem = compiled.memory_analysis()
     assert mem is None or mem.temp_size_in_bytes < 16 * 2**30
+
+
+def test_systolic_stage_scopes_keep_kernel_names(topo, one_chip,
+                                                 compiled_mode):
+    """The systolic tiles program on a ring of two chips: its stages carry
+    their ``nng.*`` named scopes in ``op_name``, and the kernels' custom
+    calls keep the names the benchmark's matchers look for."""
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from bench.kernels import is_epilogue, is_tile
+    from repro.core.distributed.device import _systolic_fn
+    from repro.core.metrics import get_metric
+    from repro.kernels.ops import pallas_mode
+    mesh = Mesh(np.asarray(topo.devices[:2]), ("ring",))
+    fn = _systolic_fn(mesh, 1.0, get_metric("euclidean"), 128, "ring", True,
+                      pallas_mode(), "tiles")
+    n = 2 * 4096
+    args = [jax.ShapeDtypeStruct((n, 128), f32, sharding=NamedSharding(
+                mesh, P("ring", None))),
+            jax.ShapeDtypeStruct((n,), i32, sharding=NamedSharding(
+                mesh, P("ring")))]
+    text = fn.lower(*args).compile().as_text()
+    lines = [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()
+             if "tpu_custom_call" in ln]
+    # the self tile, then the pair round's forward and mirror tiles
+    assert sum(map(is_tile, lines)) == 3
+    assert sum(map(is_epilogue, lines)) == 3
+    assert all(is_tile(ln) or is_epilogue(ln) for ln in lines), lines
+    scopes = set(re.findall(r'op_name="[^"]*?/(nng\.[a-z_]+)/', text))
+    assert scopes == {"nng.tile", "nng.epilogue", "nng.merge", "nng.ring",
+                      "nng.mirror_home"}
