@@ -61,6 +61,9 @@ class RunStats:
     table_slots: int = 0           # rows x width of the neighbour tables
     pairs_selected: int = 0        # their non-SENTINEL entries of valid
                                    # rows: directed pairs before symmetry
+    csr_mirror_added: int = 0      # directed entries the row-table CSR
+                                   # path added to make the table's rows
+                                   # symmetric (0 on the general path)
     spans: list = field(default_factory=list)  # (name, parent, start_s,
                                                # end_s), perf_counter clock
 
@@ -256,12 +259,19 @@ class NNGraph:
         """Build from engine outputs: ``tables`` is an iterable of
         (ids (m,), nbrs (m, k)) SENTINEL-padded per-row neighbor arrays
         (one per engine phase — e.g. owned + ghost for the landmark
-        engine). Rows with id >= n (duplicate-padding) are dropped."""
+        engine). Rows with id >= n (duplicate-padding) are dropped.
+
+        One table whose ids are ``0..m-1`` in order (the point engine's)
+        is already a padded CSR: ``_from_row_table`` compresses it and
+        proves it symmetric instead of sorting every pair. Any other
+        input selects its pairs and sorts them. Both give the same
+        bytes."""
+        tables = [(np.asarray(ids), np.asarray(nbrs)) for ids, nbrs in tables]
+        if len(tables) == 1 and _is_row_table(n, *tables[0]):
+            return cls._from_row_table(n, tables[0][1], stats, meta)
         src_all, dst_all = [], []
         with span("nng.csr.select"):
             for ids, nbrs in tables:
-                ids = np.asarray(ids)
-                nbrs = np.asarray(nbrs)
                 valid = (ids != SENTINEL) & (ids < n)
                 ii, kk = np.nonzero((nbrs != SENTINEL) & valid[:, None])
                 src_all.append(ids[ii])
@@ -273,6 +283,55 @@ class NNGraph:
             dst = (np.concatenate(dst_all) if dst_all
                    else np.zeros(0, np.int64))
         return cls.from_directed_pairs(n, src, dst, stats, meta)
+
+    @classmethod
+    def _from_row_table(cls, n: int, nbrs, stats, meta) -> "NNGraph":
+        """The CSR of a table whose row ``i`` holds point ``i``'s neighbours.
+
+        Row ``i``'s entries in ``[0, n)`` other than ``i`` are its CSR
+        row (``nng.csr.select``). It is final when each row rises strictly
+        (sorted, no duplicates) and is symmetric: the keys ``i * n + j``
+        of the entries below the diagonal equal the sorted transposed
+        keys of those above it (``nng.csr.mirror``). Missing mirror
+        entries are added (``_add_mirrors``, counted in
+        ``csr_mirror_added``); a row not sorted or holding a duplicate
+        sends the pairs to ``from_directed_pairs``."""
+        with span("nng.csr.select"):
+            count("table_slots", nbrs.size)
+            filled = nbrs[:n] != SENTINEL
+            cols = nbrs[:n][filled]
+            count("pairs_selected", len(cols))
+            deg = filled.view(np.uint8).sum(axis=1, dtype=np.int64)
+            del filled
+            src = np.repeat(np.arange(n, dtype=np.int32), deg)
+            low, up = cols < src, cols > src
+            if len(cols) and (cols.min() < 0 or cols.max() >= n
+                              or np.count_nonzero(low)
+                              + np.count_nonzero(up) < len(cols)):
+                # duplicate-padding ids, self loops, bad ids
+                ok = (cols >= 0) & (cols < n) & (cols != src)
+                cols, src, low, up = cols[ok], src[ok], low[ok], up[ok]
+                deg = np.bincount(src, minlength=n)
+            row_ptr = np.zeros(n + 1, np.int64)
+            np.cumsum(deg, out=row_ptr[1:])
+        with span("nng.csr.mirror"):
+            rising = cols[1:] > cols[:-1]
+            starts = row_ptr[1:-1]
+            rising[starts[(starts > 0) & (starts < len(cols))] - 1] = True
+            canonical = bool(rising.all())
+            del rising
+            if canonical:
+                below = _keys(src[low], cols[low], n)
+                above_t = _keys(cols[up], src[up], n)
+                above_t.sort()
+                added = 0
+                if not np.array_equal(below, above_t):
+                    row_ptr, cols, added = _add_mirrors(
+                        n, src, cols, deg, below, above_t)
+                count("csr_mirror_added", added)
+        if not canonical:
+            return cls.from_directed_pairs(n, src, cols, stats, meta)
+        return cls(n, row_ptr, cols, stats, meta)
 
     # -- accessors ----------------------------------------------------------
     @property
@@ -353,6 +412,54 @@ class NNGraph:
     def __repr__(self):
         return (f"NNGraph(n={self.n}, edges={self.num_edges}, "
                 f"avg_deg={self.avg_degree:.2f})")
+
+
+def _is_row_table(n: int, ids, nbrs) -> bool:
+    """True for one (ids, nbrs) table whose ids are ``0..m-1``, m >= n."""
+    return (ids.ndim == 1 and nbrs.ndim == 2 and len(ids) == len(nbrs) >= n
+            and np.array_equal(ids, np.arange(len(ids))))
+
+
+def _keys(rows, cols, n: int) -> np.ndarray:
+    """int64 keys ``rows * n + cols``."""
+    key = rows.astype(np.int64)
+    key *= n
+    key += cols
+    return key
+
+
+def _add_mirrors(n: int, src, cols, deg, below, above_t):
+    """Make a CSR with strictly rising rows symmetric: ``src`` / ``cols``
+    its entries in row order, ``deg`` its row lengths, ``below`` the
+    keys of its entries below the diagonal and ``above_t`` the sorted
+    transposed keys of those above. Returns (row_ptr, col_ids, added).
+    Only the rows whose two lower segments differ are set against each
+    other, so the sorting scales with them; the rest are linear passes."""
+    below_deg = np.bincount(src[cols < src], minlength=n)
+    above_deg = np.bincount(cols[cols > src], minlength=n)
+    b_ptr = np.zeros(n + 1, np.int64)
+    np.cumsum(below_deg, out=b_ptr[1:])
+    a_ptr = np.zeros(n + 1, np.int64)
+    np.cumsum(above_deg, out=a_ptr[1:])
+    differ = below_deg != above_deg
+    # rows of equal length: compare their segments entry by entry
+    row = np.repeat(np.arange(n), below_deg)
+    same = np.flatnonzero(~differ[row])
+    shift = (a_ptr - b_ptr)[row[same]]
+    differ[row[same[below[same] != above_t[same + shift]]]] = True
+    mine = below[np.repeat(differ, below_deg)]
+    theirs = above_t[np.repeat(differ, above_deg)]
+    # add the (i, j) whose mirror (j, i) is in the table and they are
+    # not, and (j, i) for the (i, j) that are in it alone
+    lone = np.setdiff1d(mine, theirs, assume_unique=True)
+    add = np.sort(np.concatenate([
+        np.setdiff1d(theirs, mine, assume_unique=True),
+        (lone % n) * n + lone // n]))
+    at = np.searchsorted(_keys(src, cols, n), add)
+    cols = np.insert(cols, at, (add % n).astype(cols.dtype))
+    row_ptr = np.zeros(n + 1, np.int64)
+    np.cumsum(deg + np.bincount(add // n, minlength=n), out=row_ptr[1:])
+    return row_ptr, cols, len(add)
 
 
 class EpsGraph:

@@ -587,8 +587,8 @@ def build_nng(
 
     ``g.stats`` also holds the host side of the build: its ``nng.*`` spans
     (``repro.obs``) and the counters ``engine_calls``, ``compiles``,
-    ``compile_s``, ``fetch_bytes``, ``table_slots`` and
-    ``pairs_selected``.
+    ``compile_s``, ``fetch_bytes``, ``table_slots``, ``pairs_selected``
+    and ``csr_mirror_added``.
     """
     with recording() as rec:
         with span("nng.prepare"):

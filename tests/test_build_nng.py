@@ -62,6 +62,169 @@ def test_nngraph_from_neighbor_tables():
     assert g.meta["metric"] == "euclidean"
 
 
+SEN = 2**31 - 1
+
+
+def _sorted_table(n, m, k, edges, rng):
+    """(m, k) SENTINEL-padded table of a symmetric random graph on ``m``
+    ids with ``edges`` undirected edges, each row sorted ascending."""
+    a, b = rng.integers(0, m, (2, edges))
+    a, b = a[a != b], b[a != b]
+    key = np.unique(np.r_[a * m + b, b * m + a])
+    rows, cols = key // m, key % m
+    deg = np.bincount(rows, minlength=m)
+    assert deg.max() <= k
+    table = np.full((m, k), SEN, np.int32)
+    table[rows, np.arange(len(key)) - np.repeat(np.cumsum(deg) - deg, deg)] \
+        = cols
+    return table
+
+
+def _put(table, i, ids):
+    """Make row ``i`` hold ``ids``, sorted and SENTINEL-padded."""
+    table[i] = SEN
+    table[i, :len(ids)] = np.sort(ids)
+
+
+def _insert(table, i, j):
+    """Put id ``j`` into row ``i``, keeping the row sorted."""
+    _put(table, i, np.r_[table[i][table[i] != SEN], j])
+
+
+def _one_sided(table, n, count, rng):
+    """Add ``count`` entries (i, j), i != j < n, whose mirror is absent
+    and that are not there yet; returns the table and ``count``."""
+    added = set()
+    while len(added) < count:
+        i, j = (int(v) for v in rng.integers(0, n, 2))
+        if (i == j or j in table[i] or i in table[j]
+                or (j, i) in added):
+            continue
+        _insert(table, i, j)
+        added.add((i, j))
+    return table, count
+
+
+def _table_case(case, rng):
+    """(n, tables, takes the row-table path, mirror entries it adds)."""
+    n, k = 60, 24
+    table = _sorted_table(n, n, k, 150, rng)
+    ids = np.arange(n)
+    if case == "symmetric":
+        return n, [(ids, table)], True, 0
+    if case == "padding":
+        m = n + 4                     # rows n.. duplicate rows 0..3
+        table = _sorted_table(n, m, k, 170, rng)
+        return n, [(np.arange(m), table)], True, 0
+    if case == "self_loop":
+        _insert(table, 5, 5)
+        return n, [(ids, table)], True, 0
+    if case == "one_sided":
+        table, added = _one_sided(table, n, 9, rng)
+        return n, [(ids, table)], True, added
+    if case == "swapped_mirror":
+        # a row keeps its length: one mirror dropped, one one-sided id in
+        x = next(i for i in range(n - 1, 0, -1)
+                 if (table[i] < i).any() and (table[i] == SEN).any())
+        row = table[x][table[x] != SEN]
+        b = next(j for j in range(x) if j not in row and x not in table[j])
+        _put(table, x, np.r_[row[1:], b])
+        return n, [(ids, table)], True, 2
+    if case == "unsorted":
+        row = table[3][table[3] != SEN]
+        table[3, :len(row)] = row[::-1]
+        return n, [(ids, table)], False, 0
+    if case == "duplicate":
+        row = table[7][table[7] != SEN]
+        table[7, :len(row) + 1] = np.sort(np.r_[row, row[0]])
+        return n, [(ids, table)], False, 0
+    if case == "empty_rows":
+        for i in (0, 11, 59):
+            table[table == i] = SEN
+        table[[0, 11, 59]] = SEN
+        table = np.sort(table, axis=1)
+        return n, [(ids, table)], True, 0
+    if case == "n1":
+        return 1, [(np.arange(2), np.array([[1, SEN], [0, SEN]]))], True, 0
+    if case == "spatial":
+        half = n // 2
+        return n, [(ids[:half], table[:half]),
+                   (ids[half:], table[half:])], False, 0
+    if case == "permuted_ids":
+        order = rng.permutation(n)
+        return n, [(ids[order], table[order])], False, 0
+    raise ValueError(case)
+
+
+@pytest.mark.parametrize("case", [
+    "symmetric", "padding", "self_loop", "one_sided", "swapped_mirror",
+    "unsorted",
+    "duplicate", "empty_rows", "n1", "spatial", "permuted_ids"])
+def test_row_table_csr_matches_the_general_path(case):
+    """One table with ids 0..m-1 takes the row-table path; its CSR is
+    byte-identical to ``from_directed_pairs`` of the same pairs, with the
+    same counters, and ``csr_mirror_added`` counts the mirrors it added."""
+    from repro.obs import recording
+
+    n, tables, row_path, added = _table_case(
+        case, np.random.default_rng([ord(c) for c in case]))
+    src, dst, pairs = [], [], 0
+    for ids, nbrs in tables:
+        ii, kk = np.nonzero((nbrs != SEN) & (ids < n)[:, None])
+        src.append(ids[ii])
+        dst.append(nbrs[ii, kk])
+        pairs += len(ii)
+    want = NNGraph.from_directed_pairs(n, np.concatenate(src),
+                                       np.concatenate(dst))
+    with recording() as rec:
+        got = NNGraph.from_neighbor_tables(n, tables)
+    assert got.row_ptr.dtype == want.row_ptr.dtype
+    assert got.col_ids.dtype == want.col_ids.dtype
+    assert np.array_equal(got.row_ptr, want.row_ptr)
+    assert np.array_equal(got.col_ids, want.col_ids)
+    assert rec.counts["table_slots"] == sum(t.size for _, t in tables)
+    assert rec.counts["pairs_selected"] == pairs
+    names = {name for name, *_ in rec.spans}
+    assert ("nng.csr.mirror" in names) == (len(tables) == 1
+                                           and case != "permuted_ids")
+    assert ("nng.csr.sort" in names) == (not row_path)
+    if row_path:
+        assert rec.counts["csr_mirror_added"] == added
+        d = np.concatenate(dst)
+        kept = np.count_nonzero((d < n) & (d != np.concatenate(src)))
+        assert int(got.row_ptr[-1]) == kept + added
+    else:
+        assert "csr_mirror_added" not in rec.counts
+
+
+@pytest.mark.parametrize("metric,traversal", [
+    ("euclidean", "tiles"), ("euclidean", "tree"), ("hamming", "tiles")])
+def test_build_nng_point_row_table_matches_brute(metric, traversal):
+    """The point engine on one CPU device takes the row-table path: the
+    exact graph of the brute-force oracle, and no mirror entry added on
+    points whose distances are exact in fp32."""
+    from repro.nng import build_nng
+
+    pts = synthetic_pointset(300, 8, metric, seed=17)
+    if metric == "euclidean":
+        # a 2^-5 grid keeps every fp32 term of the L2 expansion exact
+        pts = (np.round(pts * 32) / 32).astype(np.float32)
+        x = pts.astype(np.float64)
+        d2 = np.unique(((x[:, None] - x[None]) ** 2).sum(-1))
+        k = int(np.searchsorted(d2, 1.0))
+        eps = float(np.sqrt(0.5 * (d2[k - 1] + d2[k])))
+    else:
+        eps = 40
+    g = build_nng(pts, eps, metric=metric, traversal=traversal, k_cap=64)
+    oracle = brute_force_graph(pts, eps, metric)
+    assert oracle.num_edges > 100
+    assert g == oracle
+    assert g.stats.csr_mirror_added == 0
+    assert g.stats.pairs_selected == 2 * oracle.num_edges
+    names = {name for name, *_ in g.stats.spans}
+    assert "nng.csr.mirror" in names and "nng.csr.sort" not in names
+
+
 def test_symmetric_difference_matches_set_semantics():
     """The np.setxor1d fast path must return exactly what the old
     Python-set xor did, for disjoint, overlapping, identical, and empty

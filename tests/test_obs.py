@@ -17,6 +17,7 @@ PARENTS = {"nng.forest": {"nng.prepare"},
            "nng.wait": {"nng.run", "nng.rerun"},
            "nng.check": {"nng.run"},
            "nng.csr.select": {"nng.csr"},
+           "nng.csr.mirror": {"nng.csr"},
            "nng.csr.sort": {"nng.csr"},
            "nng.csr.rows": {"nng.csr"}}
 
@@ -60,7 +61,12 @@ def test_span_tree_and_counters(partition, traversal):
     st = g.stats
     assert st.replans == 0 and st.engine_calls == 2
     names = _names(st)
-    expected = TOP - {"nng.grow"} | set(PARENTS) - {"nng.forest"}
+    # the point engine's one row table takes the row-table CSR path, the
+    # spatial engine's owned and ghost tables the general one
+    general = {"nng.csr.sort", "nng.csr.rows"}
+    skipped = {"nng.forest"} | (general if partition == "point"
+                                else {"nng.csr.mirror"})
+    expected = TOP - {"nng.grow"} | set(PARENTS) - skipped
     if traversal == "tree":
         expected.add("nng.forest")
         assert st.build_s == _seconds(st, "nng.forest") > 0
@@ -76,6 +82,7 @@ def test_span_tree_and_counters(partition, traversal):
         assert st.table_slots == N * k_cap
         assert st.fetch_bytes == N * k_cap * 4
         assert st.pairs_selected == 2 * g.num_edges
+        assert st.csr_mirror_added == 0
     else:
         # owned and ghost tables, ids fetched with them
         assert st.table_slots % k_cap == 0
