@@ -64,6 +64,8 @@ class RunStats:
     csr_mirror_added: int = 0      # directed entries the row-table CSR
                                    # path added to make the table's rows
                                    # symmetric (0 on the general path)
+    ring_bytes: float = 0.0        # bytes each rank sent by ppermute,
+                                   # summed over the point engine's calls
     spans: list = field(default_factory=list)  # (name, parent, start_s,
                                                # end_s), perf_counter clock
 

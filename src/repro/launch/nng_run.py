@@ -224,7 +224,8 @@ def main(argv=None):
     print(f"engine_calls={st.engine_calls} compiles={st.compiles} "
           f"({st.compile_s:.2f}s) fetch_bytes={st.fetch_bytes} table_fill="
           f"{100 * st.pairs_selected / max(st.table_slots, 1):.2f}% "
-          f"csr_mirror_added={st.csr_mirror_added}")
+          f"csr_mirror_added={st.csr_mirror_added} "
+          f"ring_bytes={st.ring_bytes:.0f}")
     for name, secs in totals(st.spans).items():
         print(f"  {name:<15} {secs:.4f}s")
 

@@ -183,6 +183,7 @@ class PointPartitionEngine(Engine):
         return self.k_cap
 
     def run(self, k_cap):
+        count("ring_bytes", self._ring_hop_bytes(k_cap))
         return systolic_run(
             self.points, self.eps, self.mesh, metric=self.metric,
             k_cap=k_cap, prune=self.prune, traversal=self.traversal,
@@ -248,6 +249,14 @@ class PointPartitionEngine(Engine):
             hops = rounds + 1 if self.overlap else rounds
             bytes_["ring_points"] = float(nranks * hops * pt_hop)
         return bytes_
+
+    def _ring_hop_bytes(self, k_cap: int) -> float:
+        """Bytes each rank sends by ``ppermute`` in one engine call: the
+        ring channels of ``_ring_comm_bytes`` (all but the block-summary
+        all_gather), per rank."""
+        per_run = self._ring_comm_bytes(k_cap)
+        return sum(v for ch, v in per_run.items()
+                   if ch != "ring_summary") / self.mesh.size
 
     def run_stats(self, out, k_cap) -> RunStats:
         nranks = self.mesh.size
@@ -587,8 +596,8 @@ def build_nng(
 
     ``g.stats`` also holds the host side of the build: its ``nng.*`` spans
     (``repro.obs``) and the counters ``engine_calls``, ``compiles``,
-    ``compile_s``, ``fetch_bytes``, ``table_slots``, ``pairs_selected``
-    and ``csr_mirror_added``.
+    ``compile_s``, ``fetch_bytes``, ``table_slots``, ``pairs_selected``,
+    ``csr_mirror_added`` and, on the point partition, ``ring_bytes``.
     """
     with recording() as rec:
         with span("nng.prepare"):

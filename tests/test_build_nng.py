@@ -7,6 +7,7 @@ oracle in the engines' declared arithmetic), CSR invariants hold, a
 user-defined plain-jnp metric (no Pallas kernels) runs end-to-end through
 the fallback path, and the deprecated tuple APIs still return the PR 4
 shapes (with a DeprecationWarning)."""
+import os
 import warnings
 
 import numpy as np
@@ -197,12 +198,7 @@ def test_row_table_csr_matches_the_general_path(case):
         assert "csr_mirror_added" not in rec.counts
 
 
-@pytest.mark.parametrize("metric,traversal", [
-    ("euclidean", "tiles"), ("euclidean", "tree"), ("hamming", "tiles")])
-def test_build_nng_point_row_table_matches_brute(metric, traversal):
-    """The point engine on one CPU device takes the row-table path: the
-    exact graph of the brute-force oracle, and no mirror entry added on
-    points whose distances are exact in fp32."""
+def _row_table_matches_brute(metric, traversal):
     from repro.nng import build_nng
 
     pts = synthetic_pointset(300, 8, metric, seed=17)
@@ -223,6 +219,41 @@ def test_build_nng_point_row_table_matches_brute(metric, traversal):
     assert g.stats.pairs_selected == 2 * oracle.num_edges
     names = {name for name, *_ in g.stats.spans}
     assert "nng.csr.mirror" in names and "nng.csr.sort" not in names
+    nranks = g.meta["nranks"]
+    if nranks > 1:
+        # the ring's ppermute bytes per rank: the priming hop and `rounds`
+        # block hops (points + first id), `rounds` mirror hops and the hop
+        # home (ids + counts), in each engine call
+        n_loc, rounds, k = len(pts) // nranks, nranks // 2, g.meta["plan"]
+        hops = (rounds + 1) * (n_loc * pts.shape[1] * pts.itemsize + 4
+                               + n_loc * k * 4 + n_loc * 4)
+        assert g.stats.replans == 0
+        assert g.stats.ring_bytes == g.stats.engine_calls * hops
+    else:
+        assert g.stats.ring_bytes == 0
+
+
+@pytest.mark.parametrize("metric,traversal,nranks", [
+    pytest.param("euclidean", "tiles", 1, id="euclidean-tiles"),
+    pytest.param("euclidean", "tree", 1, id="euclidean-tree"),
+    pytest.param("hamming", "tiles", 1, id="hamming-tiles"),
+    pytest.param("euclidean", "tiles", 4, id="euclidean-tiles-ring4")])
+def test_build_nng_point_row_table_matches_brute(metric, traversal, nranks):
+    """The point engine takes the row-table path: the exact graph of the
+    brute-force oracle, and no mirror entry added on points whose
+    distances are exact in fp32. On a ring of four host devices each row
+    of the table comes from two tiles with swapped operands: the forward
+    tile where its block stays home and the mirror tile where it visits."""
+    if nranks == 1:
+        _row_table_matches_brute(metric, traversal)
+        return
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = run_subprocess(
+        f"import sys; sys.path.insert(0, {root!r})\n"
+        "from tests.test_build_nng import _row_table_matches_brute\n"
+        f"_row_table_matches_brute({metric!r}, {traversal!r})\n"
+        "print('ROW_TABLE_RING_OK')", devices=nranks)
+    assert "ROW_TABLE_RING_OK" in out
 
 
 def test_symmetric_difference_matches_set_semantics():

@@ -15,6 +15,7 @@ this file.
 from __future__ import annotations
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -184,3 +185,80 @@ def test_systolic_stage_scopes_keep_kernel_names(topo, one_chip,
     scopes = set(re.findall(r'op_name="[^"]*?/(nng\.[a-z_]+)/', text))
     assert scopes == {"nng.tile", "nng.epilogue", "nng.merge", "nng.ring",
                       "nng.mirror_home"}
+
+
+_SHAPE = re.compile(r"^%\S+ = [a-z]+(\d+)\[([\d,]*)\]")
+# in a compiled module's text operands are bare ``%names``: the opcode is
+# the one word before ``(%``
+_PERMUTE = re.compile(r" (collective-permute(?:-start|-done)?)\(%")
+
+
+def _computations(text):
+    """{name: [operation lines]} of a compiled module's text."""
+    comps, cur = {}, None
+    for ln in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?(%\S+) .*\{$", ln)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif ln.startswith("  ") and cur is not None:
+            cur.append(ln.strip().removeprefix("ROOT "))
+    return comps
+
+
+def _hop_bytes(line):
+    """Bytes one ``collective-permute`` moves: its result, which has the
+    operand's shape (the ``-done`` half or the synchronous operation)."""
+    bits, dims = _SHAPE.match(line).groups()
+    return int(bits) // 8 * int(np.prod([int(d) for d in dims.split(",")
+                                         if d]))
+
+
+def test_ring4_sift_program_fits_and_its_hops_match_the_counter(
+        topo, one_chip, compiled_mode):
+    """The ``sift-sparse-ring4`` cell's own program: 2^19 x 128 points on
+    a ring of the four chips of a described ``v5e:2x2``, k_cap 896. It
+    fits a chip's 16 GiB; its ring hops are ``collective-permute``
+    operations that ``ring_exposed_ms`` matches and nothing else is; and
+    their bytes per rank, loop trips counted, are ``RunStats.ring_bytes``
+    of one engine call."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from bench.ring import is_ring_hop
+    from repro.core.distributed.device import _systolic_fn
+    from repro.core.metrics import get_metric
+    from repro.kernels.ops import pallas_mode
+    from repro.nng import PointPartitionEngine
+    n, dim, k_cap, nranks = 2**19, 128, 896, 4
+    mesh = Mesh(np.asarray(topo.devices[:nranks]), ("ring",))
+    fn = _systolic_fn(mesh, 3.07, get_metric("euclidean"), k_cap, "ring",
+                      True, pallas_mode(), "tiles")
+    args = [jax.ShapeDtypeStruct((n, dim), f32, sharding=NamedSharding(
+                mesh, P("ring", None))),
+            jax.ShapeDtypeStruct((n,), i32, sharding=NamedSharding(
+                mesh, P("ring")))]
+    compiled = fn.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 16 * 2**30
+
+    comps = _computations(compiled.as_text())
+    ops = [ln for lines in comps.values() for ln in lines]
+    hops = [ln for ln in ops if _PERMUTE.search(ln)]
+    assert len(hops) == 16                  # 8 start / done pairs
+    assert [ln for ln in ops if is_ring_hop(ln)] == hops
+    # the ring's loop runs rounds = nranks // 2 trips: a hop in its body
+    # counts once per trip
+    trips = {}
+    for ln in ops:
+        m = re.search(r" while\(.*condition=(%\S+), body=(%\S+?),", ln)
+        if m:
+            [bound] = re.findall(r"constant\((\d+)\)",
+                                 "\n".join(comps[m.group(1)]))
+            trips[m.group(2)] = int(bound)
+    assert set(trips.values()) == {nranks // 2}
+    sent = sum(trips.get(name, 1) * _hop_bytes(ln)
+               for name, lines in comps.items() for ln in lines
+               if _PERMUTE.search(ln) and "-start(" not in ln)
+    engine = PointPartitionEngine(
+        np.broadcast_to(f32(0), (n, dim)), 3.07, mesh, "euclidean",
+        k_cap=k_cap)
+    assert sent == engine._ring_hop_bytes(k_cap) == 1_612_185_612
