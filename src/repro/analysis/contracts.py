@@ -376,7 +376,7 @@ def default_contracts() -> list[KernelContract]:
         KernelContract(
             name="bits_to_cols",
             kernel_trace=lambda: (
-                lambda b: be.bits_to_cols_pallas(b, 128, tq=128),
+                lambda b: be.bits_to_cols_pallas(b, 128, tq=128)[0],
                 (_sds((128, 128), u32),)),
             oracle_trace=lambda: (
                 lambda b: be.bits_to_cols_ref(b, 128),

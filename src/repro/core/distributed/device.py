@@ -93,7 +93,7 @@ from repro.obs import span
 # over word popcounts in VMEM replaces the old two-pass ``lax.top_k``
 # extraction — same contract (k smallest hit columns/ids, ascending,
 # padded), bit-identical output, no dense candidate array
-from repro.kernels.ops import (bits_to_ids as _bits_to_ids_op,
+from repro.kernels.ops import (bits_to_ids_scanned as _bits_to_ids_op,
                                bits_to_gathered_ids as _bits_to_gathered_ids,
                                leaf_range_pack as _leaf_range_pack)
 
@@ -159,10 +159,17 @@ def _merge_ids(buf, new_ids):
         return jnp.sort(cat, axis=-1)[..., :k]
 
 
-def _bits_to_ids(bits, id0, k_cap):
-    """The bitmask epilogue: hit words -> (m, k_cap) ids from ``id0``."""
+def _bits_to_ids_scanned(bits, id0, k_cap):
+    """The bitmask epilogue: hit words -> ((m, k_cap) ids from ``id0``,
+    (2,) float32 [(slot, chunk) pairs its kernel scanned, pairs a full
+    scan takes])."""
     with jax.named_scope("nng.epilogue"):
         return _bits_to_ids_op(bits, id0, k_cap)
+
+
+def _bits_to_ids(bits, id0, k_cap):
+    """The bitmask epilogue: hit words -> (m, k_cap) ids from ``id0``."""
+    return _bits_to_ids_scanned(bits, id0, k_cap)[0]
 
 
 def _ring_permute(arrays, axis, perm, scope="nng.ring"):
@@ -385,38 +392,37 @@ def _systolic_local(x, ids, *, axis, nranks, eps, metric, k_cap, prune,
     # the WHOLE tile evaluation — kernel, id extraction, merge — sits
     # inside a cond so a pruned round costs only the permutes
     def _eval_pair(y, yid0, acc):
-        nbrs_, cnt_, ynbrs_, ycnt_ = acc
+        nbrs_, cnt_, ynbrs_, ycnt_, scan_ = acc
         fc, fb = tile_bits(x, y)     # visiting pts near my rows
         rc, rb = tile_bits(y, x)     # my pts near visiting rows (mirror)
-        cnt_ = cnt_ + fc
-        nbrs_ = _merge_ids(nbrs_, _bits_to_ids(fb, yid0, k_cap))
-        ycnt_ = ycnt_ + rc
-        ynbrs_ = _merge_ids(ynbrs_, _bits_to_ids(rb, id0, k_cap))
-        return nbrs_, cnt_, ynbrs_, ycnt_
+        fids, fscan = _bits_to_ids_scanned(fb, yid0, k_cap)
+        rids, rscan = _bits_to_ids_scanned(rb, id0, k_cap)
+        return (_merge_ids(nbrs_, fids), cnt_ + fc,
+                _merge_ids(ynbrs_, rids), ycnt_ + rc, scan_ + fscan + rscan)
 
     def step_serial(r, carry):
         # strict rotate-then-evaluate: round r's tile waits on round r's hop
-        y, yid0, ynbrs, ycnt, nbrs, cnt = carry
+        y, yid0, ynbrs, ycnt, nbrs, cnt, scan = carry
         y, yid0, ynbrs, ycnt = _ring_permute((y, yid0, ynbrs, ycnt), axis,
                                              perm)
-        nbrs, cnt, ynbrs, ycnt = jax.lax.cond(
+        nbrs, cnt, ynbrs, ycnt, scan = jax.lax.cond(
             do_eval[r], lambda acc: _eval_pair(y, yid0, acc),
-            lambda acc: acc, (nbrs, cnt, ynbrs, ycnt))
-        return y, yid0, ynbrs, ycnt, nbrs, cnt
+            lambda acc: acc, (nbrs, cnt, ynbrs, ycnt, scan))
+        return y, yid0, ynbrs, ycnt, nbrs, cnt, scan
 
     def step_overlap(r, carry):
         # double-buffered: the carry block already ARRIVED (hop issued last
         # iteration / pre-loop); issue hop r+1 first, then evaluate round r
         # — permute and kernels are dependency-free, so they overlap
-        y, yid0, ynbrs, ycnt, nbrs, cnt = carry
+        y, yid0, ynbrs, ycnt, nbrs, cnt, scan = carry
         y_next, yid_next = _ring_permute((y, yid0), axis, perm)
         # mirror accumulator rides one hop behind the block: permuted here,
         # merged by this round's eval (also overlaps the kernels)
         ynbrs, ycnt = _ring_permute((ynbrs, ycnt), axis, perm)
-        nbrs, cnt, ynbrs, ycnt = jax.lax.cond(
+        nbrs, cnt, ynbrs, ycnt, scan = jax.lax.cond(
             do_eval[r], lambda acc: _eval_pair(y, yid0, acc),
-            lambda acc: acc, (nbrs, cnt, ynbrs, ycnt))
-        return y_next, yid_next, ynbrs, ycnt, nbrs, cnt
+            lambda acc: acc, (nbrs, cnt, ynbrs, ycnt, scan))
+        return y_next, yid_next, ynbrs, ycnt, nbrs, cnt, scan
 
     nbrs0 = jnp.full((n_loc, k_cap), SENTINEL, dtype=jnp.int32)
     cnt0 = jnp.zeros((n_loc,), dtype=jnp.int32)
@@ -433,15 +439,17 @@ def _systolic_local(x, ids, *, axis, nranks, eps, metric, k_cap, prune,
     bits0 = bits0.at[rows, wsel].set(
         bits0[rows, wsel] & ~(jnp.uint32(1) << bsel))
     cnt = _popcount_rows(bits0)
-    nbrs = _merge_ids(nbrs0, _bits_to_ids(bits0, id0, k_cap))
+    ids0, scan = _bits_to_ids_scanned(bits0, id0, k_cap)
+    nbrs = _merge_ids(nbrs0, ids0)
     if rounds > 0:
         if overlap:
-            _, _, ynbrs, ycnt, nbrs, cnt = jax.lax.fori_loop(
+            _, _, ynbrs, ycnt, nbrs, cnt, scan = jax.lax.fori_loop(
                 1, rounds + 1, step_overlap,
-                (y1, yid1, nbrs0, cnt0, nbrs, cnt))
+                (y1, yid1, nbrs0, cnt0, nbrs, cnt, scan))
         else:
-            _, _, ynbrs, ycnt, nbrs, cnt = jax.lax.fori_loop(
-                1, rounds + 1, step_serial, (x, id0, nbrs0, cnt0, nbrs, cnt))
+            _, _, ynbrs, ycnt, nbrs, cnt, scan = jax.lax.fori_loop(
+                1, rounds + 1, step_serial,
+                (x, id0, nbrs0, cnt0, nbrs, cnt, scan))
         # each block's mirror accumulator sits `rounds` hops downstream of
         # its home rank; one permute returns it
         perm_home = [(i, (i + rounds) % nranks) for i in range(nranks)]
@@ -456,7 +464,7 @@ def _systolic_local(x, ids, *, axis, nranks, eps, metric, k_cap, prune,
     dists = (jnp.sum(do_eval.astype(jnp.float32))
              * jnp.float32(float(n_loc) * float(n_loc)))
     return (nbrs, cnt, overflow, tiles_skipped[None], dists[None],
-            jnp.zeros((1,), jnp.float32))
+            jnp.zeros((1,), jnp.float32), scan[None])
 
 
 def _systolic_local_tree(x, ids, *forest_arrays, axis, nranks, eps, metric,
@@ -532,7 +540,7 @@ def _systolic_local_tree(x, ids, *forest_arrays, axis, nranks, eps, metric,
         cnt = cnt + ycnt
     overflow = jnp.any(cnt > k_cap)[None]
     return (nbrs, cnt, overflow, tiles_skipped[None], dists[None],
-            pruned[None])
+            pruned[None], jnp.zeros((1, 2), jnp.float32))
 
 
 def _systolic_local_tree_split(x, ids, *forest_arrays, axis, nranks, eps,
@@ -660,7 +668,7 @@ def _systolic_local_tree_split(x, ids, *forest_arrays, axis, nranks, eps,
         cnt = cnt + ycnt
     overflow = jnp.any(cnt > k_cap)[None]
     return (nbrs, cnt, overflow, tiles_skipped[None], dists[None],
-            pruned[None])
+            pruned[None], jnp.zeros((1, 2), jnp.float32))
 
 
 def plan_ring_schedule(points, nranks: int, eps: float, *,
@@ -776,7 +784,7 @@ def _systolic_fn(mesh, eps, metric, k_cap, axis, prune, pallas_mode,
         body, mesh,
         in_specs=in_specs,
         out_specs=(P(axis, None), P(axis), P(axis), P(axis), P(axis),
-                   P(axis)),
+                   P(axis), P(axis, None)),
     ))
 
 
@@ -812,7 +820,7 @@ def systolic_run(
     keeps the strict rotate-then-evaluate bodies for A/B timing.
 
     Returns (nbrs, cnt, overflow, tiles_skipped, dists_evaluated,
-    nodes_pruned):
+    nodes_pruned, epilogue_scan):
       - nbrs (n, k_cap) int32 neighbor ids (SENTINEL-padded),
       - cnt (n,) exact neighbor counts,
       - overflow (nranks,) bool — grow k_cap and re-run if any is set
@@ -823,7 +831,10 @@ def systolic_run(
         rank (dense n_loc² per evaluated round on the tiles path; frontier
         pairs on the tree path; fp32 so paper-scale counts can't wrap),
       - nodes_pruned (nranks,) float32 — tree-path frontier pairs whose
-        subtree was discarded (0 on the tiles path).
+        subtree was discarded (0 on the tiles path),
+      - epilogue_scan (nranks, 2) float32 — per rank, the (slot, chunk)
+        pairs its ``bits_to_cols`` calls scanned and the pairs a full scan
+        takes (``ops.bits_to_cols_scanned``; 0, 0 on the tree path).
 
     ``points`` rows must be a multiple of the ring size (pad upstream with
     far-away sentinel points if needed; repro.launch handles this).
@@ -860,7 +871,7 @@ def systolic_nng(points, eps, mesh, **kw):
         "systolic_nng is deprecated; use repro.nng.build_nng(..., "
         "partition='point') or repro.core.distributed.systolic_run",
         DeprecationWarning, stacklevel=2)
-    return systolic_run(points, eps, mesh, **kw)
+    return systolic_run(points, eps, mesh, **kw)[:6]
 
 
 # ---------------------------------------------------------------------------

@@ -66,6 +66,13 @@ class RunStats:
                                    # symmetric (0 on the general path)
     ring_bytes: float = 0.0        # bytes each rank sent by ppermute,
                                    # summed over the point engine's calls
+    epilogue_scan_pct: float | None = None  # 100 x (slot, chunk) pairs the
+                                   # bits_to_cols kernel scanned / pairs a
+                                   # full scan takes, over every epilogue
+                                   # call of all ranks in the final engine
+                                   # call (the steady re-run repeats the
+                                   # warm run); None where not counted
+                                   # (tree traversal, spatial partition)
     spans: list = field(default_factory=list)  # (name, parent, start_s,
                                                # end_s), perf_counter clock
 

@@ -78,13 +78,52 @@ def _select_nth_set_bit(word, r):
 
 
 _CHUNK = 128     # word rows per triangular prefix-sum block
+_GROUP = 8       # output slots ranked together: one sublane tile of out
 
 
-def _bits_cols_kernel(bits_ref, out_ref, cume_ref, cumi_ref):
+def bits_cols_bounds(words_t, k: int, tq: int):
+    """What each tq-row block's prefix counts prove about its slots:
+    (W, m) int32 lane-major words (``_to_lanes``; m % tq == 0,
+    W % 128 == 0, k % 8 == 0) -> (bounds, scanned).
+
+    Chunk c of 128 words can hold output slot j of some row of the block
+    only if lo_c <= j < min(hi_c, k), where lo_c is the fewest set bits any
+    row has before the chunk and hi_c the most any row has through it.
+    ``bounds`` (m // tq, 1, 2C + 1) int32, C = W // 128: g0_c for each
+    chunk, then g1_c, then the 8-slot groups below the block's largest row
+    count. The kernel reads chunk c for the groups [g0_c, g1_c), those that
+    meet its slots (none where it holds none). ``scanned`` (float32): the
+    (slot, chunk) pairs the kernel scans, 8 x the sum of g1_c - g0_c over
+    blocks and chunks."""
+    w, m = words_t.shape
+    nc, nb = w // _CHUNK, m // tq
+    # set bits per (chunk, row): a 0/1 chunk-membership contraction on the
+    # MXU, which reads the words once (popcounts <= 32, sums <= 4096: exact)
+    member = (jax.lax.broadcasted_iota(jnp.int32, (nc, w), 1) // _CHUNK
+              == jax.lax.broadcasted_iota(jnp.int32, (nc, w), 0))
+    pc = jax.lax.dot_general(
+        member.astype(jnp.bfloat16),
+        jax.lax.population_count(words_t).astype(jnp.bfloat16),
+        (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(jnp.int32)
+    cumi = jnp.cumsum(pc, axis=0)
+    lo = jnp.min((cumi - pc).reshape(nc, nb, tq), axis=2).T
+    top = jnp.minimum(jnp.max(cumi.reshape(nc, nb, tq), axis=2).T, k)
+    live = lo < top
+    g0 = jnp.where(live, lo // _GROUP, 0)
+    g1 = jnp.where(live, -(-top // _GROUP), 0)
+    groups = -(-top[:, -1:] // _GROUP)
+    return (jnp.concatenate([g0, g1, groups], axis=1)[:, None, :],
+            jnp.float32(_GROUP) * jnp.sum((g1 - g0).astype(jnp.float32)))
+
+
+def _bits_cols_kernel(bnd_ref, bits_ref, out_ref, cume_ref, cumi_ref):
     """Word-major block: bits_ref (W, TQ) int32, one row per lane;
-    out_ref (K, TQ). W % 128 == 0 (the wrapper pads with zero words)."""
+    out_ref (K, TQ); bnd_ref (1, 2C + 1) the block's ``bits_cols_bounds``
+    in SMEM. W % 128 == 0 (the wrapper pads with zero words), K % 8 == 0."""
     w, tq = bits_ref.shape
     k = out_ref.shape[0]
+    nc = w // _CHUNK
     # inclusive/exclusive per-word set-bit prefix down the word axis: a
     # lower-triangular 0/1 MXU contraction per 128-word block plus a carry
     # (popcounts <= 32 and prefixes < 2^24 are exact in bf16 x bf16 -> f32)
@@ -92,7 +131,7 @@ def _bits_cols_kernel(bits_ref, out_ref, cume_ref, cumi_ref):
              >= jax.lax.broadcasted_iota(jnp.int32, (_CHUNK, _CHUNK), 1)
              ).astype(jnp.bfloat16)
     carry = jnp.zeros((1, tq), jnp.float32)
-    for c in range(w // _CHUNK):
+    for c in range(nc):
         rows = slice(c * _CHUNK, (c + 1) * _CHUNK)
         pc = jax.lax.population_count(bits_ref[rows, :]).astype(jnp.float32)
         cs = jax.lax.dot_general(
@@ -102,48 +141,81 @@ def _bits_cols_kernel(bits_ref, out_ref, cume_ref, cumi_ref):
         cume_ref[rows, :] = (cs - pc).astype(jnp.int32)
         carry = cs[_CHUNK - 1:, :]
     total = carry.astype(jnp.int32)                   # (1, TQ)
-    widx = jax.lax.broadcasted_iota(jnp.int32, (_CHUNK, tq), 0)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (8, tq), 0)
+    # slots at or past the block's largest count hold no column of any row
+    out_ref[...] = jnp.full(out_ref.shape, NOCOL, jnp.int32)
 
-    def slot(j, _):
-        # output slot j lives in the unique word with cume <= j < cumi;
-        # one masked sublane sum per quantity recovers that word, its
-        # index and the set bits before it — no sort, no gather
-        wsel = before = word = jnp.zeros((1, tq), jnp.int32)
-        for c in range(w // _CHUNK):
-            rows = slice(c * _CHUNK, (c + 1) * _CHUNK)
-            ce = cume_ref[rows, :]
-            first = (ce <= j) & (cumi_ref[rows, :] > j)
-            wsel += jnp.sum(jnp.where(first, widx + c * _CHUNK, 0), axis=0,
-                            keepdims=True)
-            before += jnp.sum(jnp.where(first, ce, 0), axis=0, keepdims=True)
-            word += jnp.sum(jnp.where(first, bits_ref[rows, :], 0), axis=0,
-                            keepdims=True)
-        col = wsel * 32 + _select_nth_set_bit(word, j - before)
-        out_ref[pl.ds(j, 1), :] = jnp.where(j < total, col, jnp.int32(NOCOL))
-        return 0
+    def group(g):
+        # output slot j lives in the unique word with cume <= j < cumi
+        # (an unsigned 0 <= j - cume < its popcount); masked sums per
+        # 8-word tile recover that word and 32 x its index + the rank of j
+        # in it — no sort, no gather. Only the chunks whose bounds meet
+        # this group's slots are read; the others would add nothing.
+        j0 = g * _GROUP
 
-    jax.lax.fori_loop(0, k, slot, 0)
+        def scan(c, acc):
+            acc = list(acc)
+            for r in range(_CHUNK // 8):
+                base = pl.multiple_of(c * _CHUNK + r * 8, 8)
+                rows = pl.ds(base, 8)
+                ce, word = cume_ref[rows, :], bits_ref[rows, :]
+                held = (cumi_ref[rows, :] - ce).astype(jnp.uint32)
+                w32 = (sub + base) * 32
+                for t in range(_GROUP):
+                    rank = j0 + t - ce
+                    hit = rank.astype(jnp.uint32) < held
+                    acc[2 * t] += jnp.where(hit, w32 + rank, 0)
+                    acc[2 * t + 1] += jnp.where(hit, word, 0)
+            return tuple(acc)
+
+        def chunk(c, acc):
+            meets = (bnd_ref[0, c] <= g) & (g < bnd_ref[0, nc + c])
+            return jax.lax.cond(meets, lambda a: scan(c, a), lambda a: a, acc)
+
+        zero = jnp.zeros((8, tq), jnp.int32)
+        acc = jax.lax.fori_loop(0, nc, chunk, (zero,) * (2 * _GROUP))
+        for t in range(_GROUP):
+            at, word = (jnp.sum(a, axis=0, keepdims=True)
+                        for a in acc[2 * t:2 * t + 2])
+            j = j0 + t
+            col = (at & ~31) + _select_nth_set_bit(word, at & 31)
+            out_ref[pl.ds(j, 1), :] = jnp.where(j < total, col,
+                                                jnp.int32(NOCOL))
+
+    def group_if_held(g, carry):
+        pl.when(g < bnd_ref[0, 2 * nc])(lambda: group(g))
+        return carry
+
+    jax.lax.fori_loop(0, k // _GROUP, group_if_held, 0)
 
 
 def bits_to_cols_pallas(bits, k: int, *, tq: int = 128,
                         interpret: bool = False):
-    """Pallas kernel: same contract as ``bits_to_cols_ref``. One program per
-    tq-row block; the rows ride the lanes (word-major layout) and the k
-    output slots are ranked one at a time from the row block's per-word
-    prefix counts in VMEM. m % tq == 0, W % 128 == 0 (wrappers pad with
-    zero words)."""
+    """Pallas kernel: same contract as ``bits_to_cols_ref``, and the
+    (slot, chunk) pairs it scans -> ((m, k) int32, float32). One program
+    per tq-row block; the rows ride the lanes (word-major layout) and the
+    output slots are ranked eight at a time from the row block's per-word
+    prefix counts in VMEM, each group over the 128-word chunks that can
+    hold it, up to the block's largest row count (``bits_cols_bounds``,
+    one XLA pass over the words, in SMEM). m % tq == 0, W % 128 == 0,
+    k % 8 == 0 (wrappers pad)."""
     m, w = bits.shape
-    assert m % tq == 0 and w % _CHUNK == 0, (m, tq, w)
+    assert m % tq == 0 and w % _CHUNK == 0 and k % _GROUP == 0, (m, tq, w, k)
+    words_t = _to_lanes(bits)
+    bounds, scanned = bits_cols_bounds(words_t, k, tq)
+    nbnd = bounds.shape[-1]
     out_t = pl.pallas_call(
         _bits_cols_kernel,
         grid=(m // tq,),
-        in_specs=[pl.BlockSpec((w, tq), lambda i: (0, i))],
+        in_specs=[pl.BlockSpec((None, 1, nbnd), lambda i: (i, 0, 0),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec((w, tq), lambda i: (0, i))],
         out_specs=pl.BlockSpec((k, tq), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((k, m), jnp.int32),
         scratch_shapes=[pltpu.VMEM((w, tq), jnp.int32)] * 2,
         interpret=interpret,
-    )(_to_lanes(bits))
-    return out_t.T
+    )(bounds, words_t)
+    return out_t.T, scanned
 
 
 # ---------------------------------------------------------------------------

@@ -26,7 +26,7 @@ from . import ref
 from .bits_epilogue import NOCOL, SENTINEL
 from .eps_count import eps_count_pallas
 from .nng_tile import (_GBIG, _ghost_hit, _grouped_hit, _pack_words,
-                       _unpack_words)
+                       _to_lanes, _unpack_words)
 from .pairwise_hamming import pairwise_hamming_pallas
 from .pairwise_l2 import pairwise_sqdist_pallas
 from .tree_frontier import _frontier_masks_float
@@ -452,30 +452,51 @@ def _round_up(v: int, mult: int) -> int:
     return ((v + mult - 1) // mult) * mult
 
 
+def bits_to_cols_scanned(bits, k: int):
+    """``bits_to_cols`` and what its kernel scans: -> ((m, k) int32
+    columns, (2,) float32 [(slot, chunk) pairs scanned, pairs a full scan
+    takes]). Each 128-row block's slot loop stops at its largest row count
+    and ranks each group of 8 slots over the 128-word chunks that can hold
+    one of them (``bits_epilogue.bits_cols_bounds``); the pairs follow
+    from those bounds alone, so every mode reports the same."""
+    bits = jnp.asarray(bits, jnp.uint32)
+    mode = _mode()
+    m = bits.shape[0]
+    tq = 128 if m >= 128 else _round_up(max(m, 1), 8)
+    kp = _round_up(k, 8)
+    bp, _ = _pad_rows(bits, tq)
+    bp = _pad_cols(bp, 128)          # zero words select nothing
+    full = float(bp.shape[0] // tq * kp * (bp.shape[1] // 128))
+    if mode == "jnp":
+        cols = _be.bits_to_cols_ref(bits, k)
+        _, scanned = _be.bits_cols_bounds(_to_lanes(bp), kp, tq)
+    else:
+        out, scanned = _bits_cols_padded(bp, k=kp, tq=tq,
+                                         interpret=mode == "interpret")
+        cols = out[:m, :k]
+    return cols, jnp.stack([scanned, jnp.float32(full)])
+
+
 def bits_to_cols(bits, k: int) -> jnp.ndarray:
     """(m, W) packed uint32 hit words -> (m, k) int32: each row's k lowest
     set column indices, ascending, ``NOCOL``-padded — the fused epilogue
     that replaced the two chained ``lax.top_k`` passes. Deterministic (a
     rank computation, no value sort), so every mode is bit-identical."""
-    bits = jnp.asarray(bits, jnp.uint32)
-    mode = _mode()
-    if mode == "jnp":
-        return _be.bits_to_cols_ref(bits, k)
-    m = bits.shape[0]
-    tq = 128 if m >= 128 else _round_up(max(m, 1), 8)
-    bp, _ = _pad_rows(bits, tq)
-    bp = _pad_cols(bp, 128)          # zero words select nothing
-    out = _bits_cols_padded(bp, k=_round_up(k, 8), tq=tq,
-                            interpret=mode == "interpret")
-    return out[:m, :k]
+    return bits_to_cols_scanned(bits, k)[0]
+
+
+def bits_to_ids_scanned(bits, id0, k: int):
+    """Hit words over a CONTIGUOUS id block starting at ``id0`` -> ((m, k)
+    int32 neighbor ids, ascending, SENTINEL-padded; the epilogue's scan
+    pairs as ``bits_to_cols_scanned`` gives them)."""
+    cols, scan = bits_to_cols_scanned(bits, k)
+    return jnp.where(cols < jnp.int32(NOCOL), id0 + cols,
+                     jnp.int32(SENTINEL)), scan
 
 
 def bits_to_ids(bits, id0, k: int) -> jnp.ndarray:
-    """Hit words over a CONTIGUOUS id block starting at ``id0`` -> (m, k)
-    int32 neighbor ids, ascending, SENTINEL-padded."""
-    cols = bits_to_cols(bits, k)
-    return jnp.where(cols < jnp.int32(NOCOL), id0 + cols,
-                     jnp.int32(SENTINEL))
+    """``bits_to_ids_scanned`` without the scan pairs."""
+    return bits_to_ids_scanned(bits, id0, k)[0]
 
 
 def bits_to_gathered_ids(bits, ids_row, k: int) -> jnp.ndarray:

@@ -42,7 +42,7 @@ def run_systolic(pts, eps, mesh, *, metric="euclidean", k_cap=64,
     # re-run (steady-state timing lives in build_nng / the benches)
     out, k_final, _, _ = drive(engine, max_grows=max_grows,
                                steady_state=False)
-    nbrs, cnt, _ovf, skipped, dists, pruned = out
+    nbrs, cnt, _ovf, skipped, dists, pruned, _scan = out
     return nbrs, cnt, (skipped, dists, pruned), k_final
 
 
@@ -225,7 +225,8 @@ def main(argv=None):
           f"({st.compile_s:.2f}s) fetch_bytes={st.fetch_bytes} table_fill="
           f"{100 * st.pairs_selected / max(st.table_slots, 1):.2f}% "
           f"csr_mirror_added={st.csr_mirror_added} "
-          f"ring_bytes={st.ring_bytes:.0f}")
+          f"ring_bytes={st.ring_bytes:.0f} "
+          f"epilogue_scan_pct={st.epilogue_scan_pct}")
     for name, secs in totals(st.spans).items():
         print(f"  {name:<15} {secs:.4f}s")
 

@@ -264,12 +264,14 @@ class PointPartitionEngine(Engine):
         scheduled = nranks * (rounds + 1)
         if nranks % 2 == 0 and rounds > 0:
             scheduled -= nranks // 2      # halving round: one side per pair
+        scanned, full = np.asarray(out[6], np.float64).sum(axis=0)
         return RunStats(
             tiles_scheduled=float(scheduled),
             tiles_skipped=float(np.asarray(out[3]).sum()),
             dists_evaluated=float(np.asarray(out[4]).sum()),
             nodes_pruned=float(np.asarray(out[5]).sum()),
             comm_bytes=self._ring_comm_bytes(k_cap),
+            epilogue_scan_pct=100.0 * scanned / full if full else None,
         )
 
 

@@ -157,18 +157,71 @@ def _topk_cols_reference(bits, k):
     return out
 
 
-@pytest.mark.parametrize("m,w,k", [(8, 2, 16), (100, 7, 32), (256, 16, 128)])
-def test_bits_to_cols_interpret_matches_jnp(m, w, k):
-    from repro.kernels.ops import NOCOL, bits_to_cols
+def _pattern_bits(rng, m, w, pattern):
+    """(words, mask) whose row blocks make the epilogue's chunk and slot
+    bounds skip work, or forbid it: W >= 384 words is three chunks or more,
+    so the chunk bounds differ between chunks."""
+    if pattern == "random":
+        return _random_bits(rng, m, w)
+    mask = np.zeros((m, 32 * w), bool)
+    if pattern == "sparse":           # degrees 0..~150 around k
+        mask = rng.random((m, 32 * w)) < rng.uniform(0, 0.012, (m, 1))
+    elif pattern == "empty-block":    # the first 128-row block holds nothing
+        mask[128:] = rng.random((m - 128, 32 * w)) < 0.003
+    elif pattern == "one-full-row":   # every column of one row, others empty
+        mask[37] = True
+    elif pattern == "over-k":         # every row holds more than k
+        mask = rng.random((m, 32 * w)) < 0.01
+    elif pattern == "last-chunk":     # bits only in the last chunk's words
+        mask[:, 32 * 384:] = rng.random((m, 32 * (w - 384))) < 0.05
+    words = np.zeros((m, w), np.uint32)
+    for b in range(32):
+        words |= mask[:, b::32].astype(np.uint32) << np.uint32(b)
+    return words, mask
+
+
+def _scan_closed_form(words, k):
+    """numpy (slot, chunk) pairs the epilogue scans, and those of a full
+    scan: per 128-row block and 128-word chunk, 8 x the 8-slot groups that
+    meet [lo, min(hi, k)), with lo the least set bits before the chunk and
+    hi the most through it, over rows padded as ``ops.bits_to_cols`` pads
+    them."""
+    m, w = words.shape
+    up = lambda v, mult: -(-v // mult) * mult  # noqa: E731
+    tq = 128 if m >= 128 else up(m, 8)
+    kp = up(k, 8)
+    pc = np.zeros((up(m, tq), up(w, 128)), np.int64)
+    pc[:m, :w] = sum((words >> np.uint32(b)) & 1 for b in range(32))
+    chunk = pc.reshape(len(pc), -1, 128).sum(axis=2)
+    cumi = chunk.cumsum(axis=1)
+    blocks = (len(pc) // tq, tq, chunk.shape[1])
+    lo = (cumi - chunk).reshape(blocks).min(axis=1)
+    top = np.minimum(cumi.reshape(blocks).max(axis=1), kp)
+    groups = np.where(lo < top, -(-top // 8) - lo // 8, 0)
+    return 8 * groups.sum(), blocks[0] * kp * blocks[2]
+
+
+@pytest.mark.parametrize("m,w,k,pattern", [
+    pytest.param(8, 2, 16, "random", id="8-2-16"),
+    pytest.param(100, 7, 32, "random", id="100-7-32"),
+    pytest.param(256, 16, 128, "random", id="256-16-128"),
+    pytest.param(300, 384, 96, "sparse", id="300-384-96-sparse"),
+    pytest.param(256, 384, 64, "empty-block", id="256-384-64-empty-block"),
+    pytest.param(128, 384, 64, "one-full-row", id="128-384-64-one-full-row"),
+    pytest.param(200, 384, 40, "over-k", id="200-384-40-over-k"),
+    pytest.param(136, 400, 24, "last-chunk", id="136-400-24-last-chunk"),
+])
+def test_bits_to_cols_interpret_matches_jnp(m, w, k, pattern):
+    from repro.kernels.ops import NOCOL, bits_to_cols_scanned
 
     rng = np.random.default_rng(m + w)
-    bits, mask = _random_bits(rng, m, w)
+    bits, mask = _pattern_bits(rng, m, w, pattern)
     prev = os.environ.get("REPRO_PALLAS", "")
     try:
         os.environ["REPRO_PALLAS"] = "interpret"
-        ci = np.asarray(bits_to_cols(bits, k))
+        ci, si = map(np.asarray, bits_to_cols_scanned(bits, k))
         os.environ["REPRO_PALLAS"] = "jnp"
-        cj = np.asarray(bits_to_cols(bits, k))
+        cj, sj = map(np.asarray, bits_to_cols_scanned(bits, k))
     finally:
         os.environ["REPRO_PALLAS"] = prev
     assert np.array_equal(ci, cj)
@@ -177,6 +230,44 @@ def test_bits_to_cols_interpret_matches_jnp(m, w, k):
     assert np.array_equal((ci < NOCOL).sum(axis=1), np.minimum(pc, k))
     # bit order: ascending real columns, and exactly the set bits
     assert np.array_equal(ci, _topk_cols_reference(bits, k))
+    # the scan counter is the closed form of the bounds, in every mode
+    assert np.array_equal(si, sj)
+    assert tuple(si) == _scan_closed_form(bits, k)
+
+
+def _csr_words(g):
+    """The (n, ceil(n / 32)) bitmask whose row i holds g's neighbours of i
+    — the point engine's self-tile bitmask on one rank."""
+    words = np.zeros((g.n, -(-g.n // 32)), np.uint32)
+    for i in range(g.n):
+        nb = g.neighbors(i)
+        np.bitwise_or.at(words[i], nb // 32,
+                         np.uint32(1) << (nb % 32).astype(np.uint32))
+    return words
+
+
+@pytest.mark.parametrize("case", ["sparse", "full"])
+def test_epilogue_scan_pct_of_a_point_build(case, monkeypatch):
+    """``RunStats.epilogue_scan_pct`` of a one-rank point build in
+    interpret mode is the numpy closed form over its self-tile bitmask;
+    when every row block holds a row of k set bits it reads 100."""
+    from repro.nng import build_nng
+    monkeypatch.setenv("REPRO_PALLAS", "interpret")
+    rng = np.random.default_rng(16)
+    if case == "sparse":
+        # degrees up to 31: half the groups of k_cap 64 hold no slot
+        pts, eps, k_cap = rng.normal(size=(300, 4)).astype(np.float32), 1.0, 64
+    else:                 # 505 points 0.8 apart at most: 504 each
+        pts = rng.uniform(-0.2, 0.2, (505, 4)).astype(np.float32)
+        eps, k_cap = 1.0, 504
+    g = build_nng(pts, eps, partition="point", k_cap=k_cap)
+    scanned, full = _scan_closed_form(_csr_words(g), g.meta["plan"])
+    assert g.stats.epilogue_scan_pct == pytest.approx(100.0 * scanned / full)
+    if case == "full":
+        assert g.degrees().min() == 504
+        assert g.stats.epilogue_scan_pct == 100.0
+    else:
+        assert 0 < g.stats.epilogue_scan_pct < 100
 
 
 @pytest.mark.parametrize("nq,nl", [(16, 64), (130, 352), (256, 1024)])

@@ -124,8 +124,12 @@ def test_frontier_kernel_compiles(one_chip, compiled_mode, metric, dt,
         ((n,), i32), ((q, n // 32), u32))
 
 
+# the benchmark cells' self tiles (2^17 rows, 4096 words) at their k_cap:
+# sift sparse 640, the ring 896, sift dense 1408, word2bits 1536
 @pytest.mark.parametrize("m,w,k", [(4096, 128, 256), (200, 10, 64),
-                                   (16384, 512, 512)])
+                                   (16384, 512, 512), (131072, 4096, 640),
+                                   (131072, 4096, 896), (131072, 4096, 1408),
+                                   (131072, 4096, 1536)])
 def test_bits_to_cols_compiles(one_chip, compiled_mode, m, w, k):
     from repro.kernels.ops import bits_to_cols
     _compile(lambda b: bits_to_cols(b, k), one_chip, ((m, w), u32))
