@@ -8,8 +8,8 @@ from the public entry points:
 - ``repro.launch.*`` (the CLI drivers),
 - ``repro.analysis.*`` (this analyzer),
 - plus pseudo-roots for every ``repro.*`` module imported by scripts in
-  ``benchmarks/`` and ``examples/`` — host oracles that only the bench
-  harness calls are live code, not dead code.
+  ``examples/`` — a module only an example calls is live code, not dead
+  code.
 
 Test files are deliberately NOT roots: a module only its own test imports
 is the definition of an LLM-seed leftover. Keeping one anyway (e.g. a
@@ -98,10 +98,8 @@ def build_import_graph(src_root: Path) -> dict:
 
 def _script_roots(repo_root: Path, known: set) -> set:
     roots = set()
-    for sub in ("benchmarks", "examples"):
-        d = repo_root / sub
-        if not d.is_dir():
-            continue
+    d = repo_root / "examples"
+    if d.is_dir():
         for p in sorted(d.rglob("*.py")):
             try:
                 tree = ast.parse(p.read_text())
@@ -138,8 +136,8 @@ def reachable(graph: dict, roots: set) -> set:
 
 def dead_modules(src_root: Path, repo_root: Path | None = None) -> list:
     src_root = Path(src_root)
-    # src_root is <repo>/src/repro — benchmarks/ and examples/ live at
-    # the repo root, two levels up
+    # src_root is <repo>/src/repro — examples/ lives at the repo root,
+    # two levels up
     repo_root = Path(repo_root) if repo_root else src_root.parent.parent
     graph = build_import_graph(src_root)
     roots = {m for m in graph
@@ -157,5 +155,5 @@ def lint_dead_modules(src_root: Path, repo_root: Path | None = None
     return [Diagnostic(
         "RA301", m,
         f"module '{m}' is unreachable from repro.nng / repro.launch / "
-        f"repro.analysis and no benchmark or example imports it")
+        f"repro.analysis and no example imports it")
         for m in dead_modules(src_root, repo_root)]

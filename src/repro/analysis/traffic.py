@@ -171,7 +171,7 @@ def _sds_like(arr):
 
 def _audit_points(n, dim, nranks, seed=0):
     """Clustered-but-mixed layout: some block pairs prune, some don't, so
-    tree+overlap gets a genuinely mixed forest/points ring schedule."""
+    the tree flavor gets a genuinely mixed forest/points ring schedule."""
     rng = np.random.default_rng(seed)
     centers = rng.uniform(0.0, 1.0, (nranks, dim))
     # half the blocks tight (prunable vs far blocks), half diffuse
@@ -182,26 +182,23 @@ def _audit_points(n, dim, nranks, seed=0):
 
 
 def audit_systolic(*, nranks=8, n=1024, dim=8, k_cap=64, eps=0.25,
-                   prune=True, traversal="tiles", overlap=True):
+                   prune=True, traversal="tiles"):
     """-> (diags, derived, formula, jaxpr, subject) for one ring config."""
     import jax.numpy as jnp
     from repro.core.distributed import device as dev
     from repro.nng import PointPartitionEngine
 
-    subject = (f"systolic[traversal={traversal},overlap={overlap},"
-               f"prune={prune}]")
+    subject = f"systolic[traversal={traversal},prune={prune}]"
     mesh = dev.make_nng_mesh(nranks)
     pts = _audit_points(n, dim, nranks)
     engine = PointPartitionEngine(
         pts, eps, mesh, "euclidean", k_cap=k_cap, prune=prune,
-        traversal=traversal, overlap=overlap, forest_backend="host")
+        traversal=traversal, forest_backend="host")
     formula = engine._ring_comm_bytes(k_cap)
 
-    ring_modes = (tuple(engine.ring_schedule)
-                  if traversal == "tree" and overlap else None)
     fn = dev._systolic_fn(mesh, float(eps), engine.metric, k_cap, "ring",
-                          prune, dev._pallas_mode(), traversal, overlap,
-                          ring_modes, "host")
+                          prune, dev._pallas_mode(), traversal,
+                          engine.ring_schedule)
     args = [jax.ShapeDtypeStruct((n, dim), engine.metric.dtype),
             jax.ShapeDtypeStruct((n,), np.int32)]
     coords_shape = None
@@ -249,8 +246,7 @@ def audit_landmark(*, nranks=8, n=1024, dim=8, eps=0.25,
     formula = engine._landmark_comm_bytes(plan)
 
     fn = dev._landmark_fn(mesh, float(eps), engine.metric, plan, "ring",
-                          dev._pallas_mode(), traversal, "host",
-                          ghost_mode)
+                          dev._pallas_mode(), traversal, ghost_mode)
     args = [jax.ShapeDtypeStruct((n, dim), engine.metric.dtype),
             jax.ShapeDtypeStruct((n,), np.int32),
             _sds_like(engine.centers.astype(engine.metric.dtype)),
@@ -278,11 +274,9 @@ def audit_landmark(*, nranks=8, n=1024, dim=8, eps=0.25,
 
 
 SYSTOLIC_CONFIGS = (
-    dict(traversal="tiles", overlap=True, prune=True),
-    dict(traversal="tiles", overlap=False, prune=True),
-    dict(traversal="tiles", overlap=True, prune=False),
-    dict(traversal="tree", overlap=True, prune=True),
-    dict(traversal="tree", overlap=False, prune=True),
+    dict(traversal="tiles", prune=True),
+    dict(traversal="tiles", prune=False),
+    dict(traversal="tree", prune=True),
 )
 LANDMARK_CONFIGS = (
     dict(traversal="tiles", ghost_mode="coll"),

@@ -27,8 +27,7 @@ This is the TPU-native, *sparsity-aware* realization described in DESIGN.md
   ``ppermute`` is issued before round r's tile evaluation consumes the
   already-received block, so the collective genuinely overlaps the kernels
   (the reference implementation's MPI_Irecv/MPI_Isend-around-compute
-  discipline) at the cost of one extra priming hop; ``overlap=False``
-  keeps the strict rotate-then-evaluate bodies as the A/B baseline. The
+  discipline) at the cost of one extra priming hop. The
   tree flavor additionally runs a SPLIT ring schedule: per round, the host
   planner (``plan_ring_schedule``) statically chooses between rotating the
   levelized forest tables (dense rounds — in-tree pruning pays for the
@@ -336,8 +335,7 @@ def _round_skip_flags(x, partner, eps, *, axis, metric, prune):
     return skip.at[0].set(False)                # self tile never skipped
 
 
-def _systolic_local(x, ids, *, axis, nranks, eps, metric, k_cap, prune,
-                    overlap=True):
+def _systolic_local(x, ids, *, axis, nranks, eps, metric, k_cap, prune):
     """Per-shard body (runs under shard_map). x: (n_loc, d), ids: (n_loc,).
 
     Symmetry halving (paper §IV-C: "we therefore only need N/2 rounds"):
@@ -348,7 +346,7 @@ def _systolic_local(x, ids, *, axis, nranks, eps, metric, k_cap, prune,
     evaluates). The fused kernel is invoked once per direction (forward and
     mirror), each writing only its bitmask + counts to HBM.
 
-    Double buffering (``overlap=True``): each loop iteration issues the
+    Double buffering: each loop iteration issues the
     ``ppermute`` that feeds round r+1 BEFORE evaluating round r's block, so
     the collective shares no data dependency with the tile kernels and the
     scheduler can genuinely run them concurrently — the reference
@@ -356,9 +354,7 @@ def _systolic_local(x, ids, *, axis, nranks, eps, metric, k_cap, prune,
     pipeline is primed with one extra hop before the loop (the round-0 self
     tile overlaps it), and the mirror accumulator rides one hop BEHIND the
     block: its permute is issued in the same iteration that merges into it,
-    so it too overlaps the kernels. ``overlap=False`` keeps the strict
-    rotate-then-evaluate schedule (every hop serializes ahead of its tile)
-    as the A/B baseline for the bench.
+    so it too overlaps the kernels.
 
     Relies on block-contiguous global ids (``ids = arange(n)`` sharded along
     the ring), so a visiting block is fully described by its first id.
@@ -400,17 +396,7 @@ def _systolic_local(x, ids, *, axis, nranks, eps, metric, k_cap, prune,
         return (_merge_ids(nbrs_, fids), cnt_ + fc,
                 _merge_ids(ynbrs_, rids), ycnt_ + rc, scan_ + fscan + rscan)
 
-    def step_serial(r, carry):
-        # strict rotate-then-evaluate: round r's tile waits on round r's hop
-        y, yid0, ynbrs, ycnt, nbrs, cnt, scan = carry
-        y, yid0, ynbrs, ycnt = _ring_permute((y, yid0, ynbrs, ycnt), axis,
-                                             perm)
-        nbrs, cnt, ynbrs, ycnt, scan = jax.lax.cond(
-            do_eval[r], lambda acc: _eval_pair(y, yid0, acc),
-            lambda acc: acc, (nbrs, cnt, ynbrs, ycnt, scan))
-        return y, yid0, ynbrs, ycnt, nbrs, cnt, scan
-
-    def step_overlap(r, carry):
+    def step(r, carry):
         # double-buffered: the carry block already ARRIVED (hop issued last
         # iteration / pre-loop); issue hop r+1 first, then evaluate round r
         # — permute and kernels are dependency-free, so they overlap
@@ -426,7 +412,7 @@ def _systolic_local(x, ids, *, axis, nranks, eps, metric, k_cap, prune,
 
     nbrs0 = jnp.full((n_loc, k_cap), SENTINEL, dtype=jnp.int32)
     cnt0 = jnp.zeros((n_loc,), dtype=jnp.int32)
-    if overlap and rounds > 0:
+    if rounds > 0:
         # prime the pipeline: hop 1 in flight while the self tile runs below
         y1, yid1 = _ring_permute((x, id0), axis, perm)
     # self tile (round 0): clear the diagonal bit (row i, column i) and take
@@ -442,14 +428,8 @@ def _systolic_local(x, ids, *, axis, nranks, eps, metric, k_cap, prune,
     ids0, scan = _bits_to_ids_scanned(bits0, id0, k_cap)
     nbrs = _merge_ids(nbrs0, ids0)
     if rounds > 0:
-        if overlap:
-            _, _, ynbrs, ycnt, nbrs, cnt, scan = jax.lax.fori_loop(
-                1, rounds + 1, step_overlap,
-                (y1, yid1, nbrs0, cnt0, nbrs, cnt, scan))
-        else:
-            _, _, ynbrs, ycnt, nbrs, cnt, scan = jax.lax.fori_loop(
-                1, rounds + 1, step_serial,
-                (x, id0, nbrs0, cnt0, nbrs, cnt, scan))
+        _, _, ynbrs, ycnt, nbrs, cnt, scan = jax.lax.fori_loop(
+            1, rounds + 1, step, (y1, yid1, nbrs0, cnt0, nbrs, cnt, scan))
         # each block's mirror accumulator sits `rounds` hops downstream of
         # its home rank; one permute returns it
         perm_home = [(i, (i + rounds) % nranks) for i in range(nranks)]
@@ -467,86 +447,10 @@ def _systolic_local(x, ids, *, axis, nranks, eps, metric, k_cap, prune,
             jnp.zeros((1,), jnp.float32), scan[None])
 
 
-def _systolic_local_tree(x, ids, *forest_arrays, axis, nranks, eps, metric,
-                         k_cap, prune):
-    """Per-shard systolic body, cover-tree traversal flavor — SERIAL
-    schedule (``overlap=False``; ``_systolic_local_tree_split`` is the
-    double-buffered production body).
-
-    The levelized forest tables describe THIS rank's block tree (built once
-    host-side by ``flat_tree.build_block_forests``). They rotate around the
-    ring together with the block: each ring step runs two level-synchronous
-    traversals instead of two dense tiles — my points query the visiting
-    block's tree (forward edges) and the visiting points query my tree
-    (mirror accumulator) — so the in-tree triangle-inequality prune now
-    fires *inside* every ring tile. Block-summary pruning still skips whole
-    rounds above it. Every hop here serializes ahead of its evaluation —
-    this body exists as the A/B baseline for the overlap bench.
-    """
-    n_loc = x.shape[0]
-    forest = DeviceForest(*[a[0] for a in forest_arrays])   # drop rank dim
-    perm = [(i, (i - 1) % nranks) for i in range(nranks)]
-    me = jax.lax.axis_index(axis)
-    rounds = nranks // 2
-    qcells = jnp.zeros((n_loc,), jnp.int32)
-
-    rr = jnp.arange(rounds + 1)
-    partner = (me + rr) % nranks
-    skip = _round_skip_flags(x, partner, eps,
-                             axis=axis, metric=metric, prune=prune)
-    if nranks % 2 == 0 and rounds > 0:
-        sched = jnp.where(rr == rounds, me < partner, True)
-    else:
-        sched = jnp.ones((rounds + 1,), bool)
-    do_eval = sched & ~skip
-    tiles_skipped = jnp.sum((sched & skip).astype(jnp.float32))
-
-    def trav(qp, qids, fr):
-        with jax.named_scope("nng.traverse"):
-            return tree_traverse(qp, qids, qcells, fr, eps, k_cap, metric)
-
-    def step(r, carry):
-        y, yids, yforest, ynbrs, ycnt, nbrs, cnt, dists, pruned = carry
-        y, yids = _ring_permute((y, yids), axis, perm)
-        yforest = DeviceForest(*_ring_permute(yforest, axis, perm))
-        ynbrs, ycnt = _ring_permute((ynbrs, ycnt), axis, perm)
-
-        def _eval(acc):
-            nbrs_, cnt_, ynbrs_, ycnt_, d_, p_ = acc
-            fn, fc, fd, fp = trav(x, ids, yforest)   # my pts vs visiting tree
-            rn, rc, rd, rp = trav(y, yids, forest)   # visiting pts vs my tree
-            return (_merge_ids(nbrs_, fn), cnt_ + fc,
-                    _merge_ids(ynbrs_, rn), ycnt_ + rc,
-                    d_ + fd + rd, p_ + fp + rp)
-
-        nbrs, cnt, ynbrs, ycnt, dists, pruned = jax.lax.cond(
-            do_eval[r], _eval, lambda acc: acc,
-            (nbrs, cnt, ynbrs, ycnt, dists, pruned))
-        return y, yids, yforest, ynbrs, ycnt, nbrs, cnt, dists, pruned
-
-    nbrs0 = jnp.full((n_loc, k_cap), SENTINEL, dtype=jnp.int32)
-    cnt0 = jnp.zeros((n_loc,), dtype=jnp.int32)
-    # round 0 (self tile): one traversal of my own tree; the global-id
-    # inequality inside tree_traverse excludes self pairs structurally
-    nbrs, cnt, dists, pruned = trav(x, ids, forest)
-    if rounds > 0:
-        (_, _, _, ynbrs, ycnt, nbrs, cnt, dists, pruned) = jax.lax.fori_loop(
-            1, rounds + 1, step,
-            (x, ids, forest, nbrs0, cnt0, nbrs, cnt, dists, pruned))
-        perm_home = [(i, (i + rounds) % nranks) for i in range(nranks)]
-        ynbrs, ycnt = _ring_permute((ynbrs, ycnt), axis, perm_home,
-                                    "nng.mirror_home")
-        nbrs = _merge_ids(nbrs, ynbrs)
-        cnt = cnt + ycnt
-    overflow = jnp.any(cnt > k_cap)[None]
-    return (nbrs, cnt, overflow, tiles_skipped[None], dists[None],
-            pruned[None], jnp.zeros((1, 2), jnp.float32))
-
-
 def _systolic_local_tree_split(x, ids, *forest_arrays, axis, nranks, eps,
                                metric, k_cap, prune, ring_modes):
     """Per-shard systolic body, tree flavor: double-buffered ring with the
-    SPLIT ring schedule (``overlap=True``, the production tree body).
+    SPLIT ring schedule.
 
     ``ring_modes[r - 1]`` statically selects what round r rotates. It is
     planned host-side (``plan_ring_schedule``) from the same block-summary
@@ -741,8 +645,7 @@ _N_FOREST = len(DeviceForest._fields)
 
 @functools.lru_cache(maxsize=64)
 def _systolic_fn(mesh, eps, metric, k_cap, axis, prune, pallas_mode,
-                 traversal, overlap=True, ring_modes=None,
-                 forest_backend="host"):
+                 traversal, ring_modes=None):
     """Memoized jitted shard_map program: rebuilding the closure per call
     defeats the jit cache (every invocation would retrace + recompile, and
     compile dominates wall clock on re-plan loops / benchmarks). Mesh and
@@ -754,31 +657,20 @@ def _systolic_fn(mesh, eps, metric, k_cap, axis, prune, pallas_mode,
     the env mid-process would silently reuse a program traced under the
     old mode. ``traversal`` selects the dense-tile vs cover-tree body
     (different arities); forest table SHAPES are not part of the key — jit
-    retraces per shape as usual. ``overlap`` picks double-buffered vs
-    serial ring bodies, and ``ring_modes`` (a per-round "forest"/"points"
-    tuple from ``plan_ring_schedule``, tree + overlap only) is static
-    because every round's rotating payload must be known at trace time —
-    a different schedule IS a different program. ``forest_backend``
-    ("host"/"device", tree only) keys the provenance of the forest tables:
-    the two builders agree on shapes for the same input, so sharing a
-    program between them would be shape-safe, but a distinct key keeps
-    host-vs-device A/B timings from poisoning each other's jit caches."""
+    retraces per shape as usual. ``ring_modes`` (a per-round
+    "forest"/"points" tuple from ``plan_ring_schedule``, tree only) is
+    static because every round's rotating payload must be known at trace
+    time — a different schedule IS a different program."""
     nranks = mesh.shape[axis]
     if traversal == "tree":
-        if overlap:
-            body = functools.partial(
-                _systolic_local_tree_split, axis=axis, nranks=nranks,
-                eps=eps, metric=metric, k_cap=k_cap, prune=prune,
-                ring_modes=ring_modes)
-        else:
-            body = functools.partial(
-                _systolic_local_tree, axis=axis, nranks=nranks, eps=eps,
-                metric=metric, k_cap=k_cap, prune=prune)
+        body = functools.partial(
+            _systolic_local_tree_split, axis=axis, nranks=nranks, eps=eps,
+            metric=metric, k_cap=k_cap, prune=prune, ring_modes=ring_modes)
         in_specs = (P(axis, None), P(axis)) + (P(axis),) * _N_FOREST
     else:
         body = functools.partial(
             _systolic_local, axis=axis, nranks=nranks, eps=eps,
-            metric=metric, k_cap=k_cap, prune=prune, overlap=overlap)
+            metric=metric, k_cap=k_cap, prune=prune)
         in_specs = (P(axis, None), P(axis))
     return jax.jit(_shard_map(
         body, mesh,
@@ -799,9 +691,7 @@ def systolic_run(
     prune: bool = True,
     traversal: str = "tiles",
     forest: dict | None = None,
-    overlap: bool = True,
     ring_schedule: tuple | None = None,
-    forest_backend: str = "host",
 ):
     """Distributed exact ε-NNG via the sparsity-aware systolic ring.
 
@@ -811,13 +701,12 @@ def systolic_run(
     + ``stack_device_forests``) so the triangle-inequality prune fires
     inside every tile, not just at block granularity.
 
-    ``overlap=True`` (default) runs the double-buffered ring: each round's
+    The ring is double-buffered: each round's
     ``ppermute`` is issued before the previous round's tile is evaluated,
     so comm genuinely overlaps compute (one extra priming hop on the tiles
     flavor). The tree flavor additionally runs the split ring schedule —
     ``ring_schedule`` is the per-round ``"forest"``/``"points"`` mode tuple
-    (computed via ``plan_ring_schedule`` when None). ``overlap=False``
-    keeps the strict rotate-then-evaluate bodies for A/B timing.
+    (computed via ``plan_ring_schedule`` when None).
 
     Returns (nbrs, cnt, overflow, tiles_skipped, dists_evaluated,
     nodes_pruned, epilogue_scan):
@@ -844,14 +733,12 @@ def systolic_run(
     n, _ = points.shape
     assert n % nranks == 0, (n, nranks)
     ids = np.arange(n, dtype=np.int32)
-    if traversal == "tree" and overlap and ring_schedule is None:
+    if traversal == "tree" and ring_schedule is None:
         ring_schedule = plan_ring_schedule(points, nranks, float(eps),
                                            metric=metric, prune=prune)
-    ring_modes = (tuple(ring_schedule)
-                  if traversal == "tree" and overlap else None)
+    ring_modes = tuple(ring_schedule) if traversal == "tree" else None
     fn = _systolic_fn(mesh, float(eps), met, k_cap, axis, prune,
-                      _pallas_mode(), traversal, overlap, ring_modes,
-                      forest_backend)
+                      _pallas_mode(), traversal, ring_modes)
     if traversal == "tree":
         assert forest is not None, "traversal='tree' needs stacked forests"
     with span("nng.put"):
@@ -1424,7 +1311,6 @@ def landmark_run(
     traversal: str = "tiles",
     forest: dict | None = None,
     cell=None,
-    forest_backend: str = "host",
     ghost_mode: str = "coll",
 ):
     """Distributed landmark ε-NNG. ``ghost_mode`` selects the Phase 4
@@ -1463,7 +1349,7 @@ def landmark_run(
             "ghost_mode='ring' needs plan.cap_rank (use "
             "plan_landmark_device, or set cap_rank explicitly)")
     fn = _landmark_fn(mesh, float(eps), met, plan, axis, _pallas_mode(),
-                      traversal, forest_backend, ghost_mode)
+                      traversal, ghost_mode)
     if traversal == "tree":
         assert forest is not None, "traversal='tree' needs stacked forests"
         assert cell is not None, ("traversal='tree' needs the cell "
@@ -1493,10 +1379,9 @@ def landmark_nng(points, eps, centers, f, mesh, plan, **kw):
 
 @functools.lru_cache(maxsize=64)
 def _landmark_fn(mesh, eps, metric, plan, axis, pallas_mode,
-                 traversal="tiles", forest_backend="host",
-                 ghost_mode="coll"):
+                 traversal="tiles", ghost_mode="coll"):
     """Memoized jitted shard_map program (see ``_systolic_fn``, including
-    the ``pallas_mode`` and ``forest_backend`` keys); the frozen
+    the ``pallas_mode`` key); the frozen
     ``LandmarkPlan`` is the static capacity key, so only genuine re-plans
     (grown capacities) pay a recompile. ``ghost_mode`` (resolved "coll" /
     "ring", never "auto") keys the Phase 4 schedule — the two modes are

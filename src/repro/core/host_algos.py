@@ -3,8 +3,7 @@
 These run the *exact* distributed algorithm structure — block partitioning,
 per-rank cover trees, ring rotation schedule, Voronoi coalescing, ghost
 exchange — with N simulated ranks in one process. They are the correctness
-reference for the device (shard_map) engine and power the paper-table
-benchmarks (phase breakdowns, comm-volume accounting, strong scaling).
+reference for the device (shard_map) engine.
 
 The device engine in ``repro.core.distributed`` runs the same math as SPMD
 programs over the TPU mesh.

@@ -240,7 +240,7 @@ def nng_tile_geometry(q: int, p: int, metric) -> tuple[int, int]:
     and ``nng_tile_bits_grouped``) use for given operand row counts — the
     single source of truth for tile tuning (now carried per-metric by the
     registry), exposed so callers can reproduce the grouped tile-block
-    accounting (benchmarks, parity tests)."""
+    accounting (parity tests)."""
     return _resolve_metric(metric).tile_shape(q, p)
 
 

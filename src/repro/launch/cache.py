@@ -2,8 +2,9 @@
 
 A cache whose directory moves between runs is never found again, so the
 directory is fixed: never a temp name, a pid or a time. Every entry point
-(``nng_run.main``, ``chip_smoke.py``, ``benchmarks/run.py``) calls
-``enable_compile_cache()`` before its first compile.
+(``nng_run.main``, ``chip_smoke.py``, ``bench/harness.py``,
+``bench/control.py``) calls ``enable_compile_cache()`` before its first
+compile.
 """
 from __future__ import annotations
 

@@ -17,7 +17,7 @@ Usage:
 
 ``run_systolic`` / ``run_landmark`` remain as thin adapters over the
 unified ``repro.nng.drive`` loop, returning the historical tuple shapes
-(benchmarks and regression tests still consume them).
+(regression tests still consume them).
 """
 from __future__ import annotations
 

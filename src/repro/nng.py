@@ -146,7 +146,7 @@ class PointPartitionEngine(Engine):
     def __init__(self, points, eps, mesh, metric, *, k_cap: int = 64,
                  prune: bool = True, traversal: str = "tiles",
                  forest: dict | None = None, axis: str = "ring",
-                 overlap: bool = True, forest_backend: str = "device"):
+                 forest_backend: str = "device"):
         self.metric = get_metric(metric)
         self.points = np.asarray(points)
         self.eps = float(eps)
@@ -155,8 +155,6 @@ class PointPartitionEngine(Engine):
         self.prune = prune
         self.traversal = traversal
         self.axis = axis
-        self.overlap = bool(overlap)
-        self.forest_backend = forest_backend
         self.build_s = 0.0
         if traversal == "tree" and forest is None:
             from repro.core.flat_tree import (build_block_forests,
@@ -174,7 +172,7 @@ class PointPartitionEngine(Engine):
         # the split ring schedule is static (part of the compiled program),
         # so plan it once per engine — the grow loop only changes k_cap
         self.ring_schedule = None
-        if traversal == "tree" and self.overlap:
+        if traversal == "tree":
             self.ring_schedule = plan_ring_schedule(
                 self.points, mesh.size, self.eps, metric=self.metric,
                 prune=self.prune)
@@ -187,9 +185,8 @@ class PointPartitionEngine(Engine):
         return systolic_run(
             self.points, self.eps, self.mesh, metric=self.metric,
             k_cap=k_cap, prune=self.prune, traversal=self.traversal,
-            forest=self.forest, axis=self.axis, overlap=self.overlap,
-            ring_schedule=self.ring_schedule,
-            forest_backend=self.forest_backend)
+            forest=self.forest, axis=self.axis,
+            ring_schedule=self.ring_schedule)
 
     def overflowed(self, out):
         return bool(np.asarray(out[2]).any())
@@ -211,11 +208,11 @@ class PointPartitionEngine(Engine):
           the block-id payload (one int32 ``id0`` scalar on the tiles
           flavor, the (n_loc,) id vector on the tree flavor). Double
           buffering pays one extra priming hop on the tiles flavor; the
-          tree flavors make exactly ``rounds`` point hops.
-        - ``ring_forest`` (tree only): the levelized forest tables — every
-          hop on the serial schedule, one jump-permute per "forest"-mode
-          round on the split schedule (a jump costs one hop's bytes no
-          matter how many positions it covers).
+          tree flavor makes exactly ``rounds`` point hops.
+        - ``ring_forest`` (tree only): the levelized forest tables — one
+          jump-permute per "forest"-mode round of the split schedule (a
+          jump costs one hop's bytes no matter how many positions it
+          covers).
         - ``ring_mirror``: the visiting block's neighbor accumulator
           ((n_loc, k_cap) ids + (n_loc,) counts) — ``rounds`` in-loop hops
           plus the final shift-``rounds`` return home.
@@ -239,15 +236,11 @@ class PointPartitionEngine(Engine):
             bytes_["ring_points"] = float(nranks * rounds * pt_hop)
             forest_hop = sum(
                 np.asarray(v).nbytes for v in self.forest.values()) / nranks
-            if self.overlap:
-                fhops = sum(m == "forest" for m in self.ring_schedule)
-            else:
-                fhops = rounds
+            fhops = sum(m == "forest" for m in self.ring_schedule)
             bytes_["ring_forest"] = float(nranks * fhops * forest_hop)
         else:
             pt_hop = n_loc * dim * item + 4
-            hops = rounds + 1 if self.overlap else rounds
-            bytes_["ring_points"] = float(nranks * hops * pt_hop)
+            bytes_["ring_points"] = float(nranks * (rounds + 1) * pt_hop)
         return bytes_
 
     def _ring_hop_bytes(self, k_cap: int) -> float:
@@ -333,7 +326,6 @@ class SpatialPartitionEngine(Engine):
             f = lpt_assignment(
                 np.bincount(self.cell, minlength=self.m_centers), nranks)
         self.f = np.asarray(f, np.int32)
-        self.forest_backend = forest_backend
         self.build_s = 0.0
         if traversal == "tree" and forest is None:
             from repro.core.flat_tree import (build_cell_forests,
@@ -412,7 +404,6 @@ class SpatialPartitionEngine(Engine):
             self.points, self.eps, self.centers, self.f, self.mesh, plan,
             metric=self.metric, traversal=self.traversal,
             forest=self.forest, cell=self.cell, axis=self.axis,
-            forest_backend=self.forest_backend,
             ghost_mode=self.resolved_ghost_mode(plan))
 
     def overflowed(self, out):
@@ -573,7 +564,6 @@ def build_nng(
     m_centers: int | None = None,
     seed: int = 0,
     max_grows: int = 8,
-    overlap: bool = True,
     forest_backend: str = "device",
     ghost_mode: str = "coll",
 ) -> NNGraph:
@@ -583,10 +573,8 @@ def build_nng(
     See the module docstring for the axes. ``k_cap`` seeds the neighbor
     list capacity (grown automatically on overflow); ``mesh`` defaults to
     a ring over all available devices; any ``n`` is accepted (duplicate
-    padding up to the mesh size, stripped from the result). ``overlap``
-    (point partition only) selects the double-buffered systolic ring —
-    ``False`` falls back to the strict rotate-then-evaluate schedule, kept
-    for A/B timing. ``forest_backend`` ("device", the default, or "host")
+    padding up to the mesh size, stripped from the result).
+    ``forest_backend`` ("device", the default, or "host")
     picks who runs the cover-forest construction for ``traversal="tree"``:
     the jit device builder (``flat_tree_device``, the end-to-end
     device-resident path) or the float64 host oracle; the forest phase is
@@ -623,8 +611,7 @@ def build_nng(
             if partition == "point":
                 engine = PointPartitionEngine(
                     run_points, eps, mesh, met, k_cap=k_cap or 64, prune=prune,
-                    traversal=traversal, overlap=overlap,
-                    forest_backend=forest_backend)
+                    traversal=traversal, forest_backend=forest_backend)
             elif partition == "spatial":
                 engine = SpatialPartitionEngine(
                     run_points, eps, mesh, met, k_cap=k_cap or 128,
@@ -649,10 +636,8 @@ def build_nng(
         }
         if traversal == "tree":
             meta["forest_backend"] = forest_backend
-        if partition == "point":
-            meta["overlap"] = bool(overlap)
-            if engine.ring_schedule is not None:
-                meta["ring_schedule"] = tuple(engine.ring_schedule)
+        if partition == "point" and engine.ring_schedule is not None:
+            meta["ring_schedule"] = tuple(engine.ring_schedule)
         if partition == "spatial":
             meta["planner"] = planner
             meta["m_centers"] = engine.m_centers

@@ -280,11 +280,11 @@ from repro.analysis.traffic import (audit_all, collect_collectives,
 
 diags, table, jaxprs = audit_all(nranks=8)
 assert diags == [], [d.render() for d in diags]
-assert len(table) == 9, sorted(table)
+assert len(table) == 7, sorted(table)
 for subject, row in table.items():
     assert row["derived"] == row["formula"], (subject, row)
 # systolic configs must account all four ring channels on the tree path
-tree = table["systolic[traversal=tree,overlap=True,prune=True]"]["derived"]
+tree = table["systolic[traversal=tree,prune=True]"]["derived"]
 assert set(tree) == {"ring_points", "ring_mirror", "ring_forest",
                      "ring_summary"}
 # landmark ghost modes: the ring path must account its rotation under the

@@ -524,7 +524,7 @@ assert st.comm_bytes["ring_mirror"] == 8 * 5 * (n_loc * 512 * 4 + n_loc * 4)
 assert st.comm_bytes["ring_summary"] == 8 * (pts.shape[1] * 4 + 4)
 assert set(st.comm_bytes) == {"ring_points", "ring_mirror", "ring_summary"}
 assert not st.overflow and st.replans == 0 and st.elapsed_s > 0
-assert g.meta["overlap"] is True and "ring_schedule" not in g.meta
+assert "ring_schedule" not in g.meta
 
 g2 = build_nng(pts, 1.0, partition="spatial", traversal="tree", k_cap=512)
 st2 = g2.stats

@@ -534,32 +534,27 @@ forest = stack_device_forests(build_block_forests(
 forest_hop = sum(np.asarray(v).nbytes for v in forest.values()) / nranks
 
 for traversal in ("tiles", "tree"):
-    for overlap in (True, False):
-        g = build_nng(pts, eps, partition="point", traversal=traversal,
-                      k_cap=256, overlap=overlap)
-        assert g == gb, (traversal, overlap, nranks)
-        st, k_fin = g.stats, g.meta["plan"]
-        assert st.elapsed_s > 0 and st.replans == 0
-        # analytic per-channel ring-byte formulas (see nng.py docstring)
-        mirror = nranks * (rounds + 1) * (n_loc * k_fin * 4 + n_loc * 4)
-        assert st.comm_bytes["ring_mirror"] == mirror, (traversal, overlap)
-        assert st.comm_bytes["ring_summary"] == nranks * (dim * 4 + 4)
-        if traversal == "tiles":
-            hops = rounds + 1 if overlap else rounds
-            assert st.comm_bytes["ring_points"] == \
-                nranks * hops * (n_loc * dim * 4 + 4), (overlap, nranks)
-            assert "ring_forest" not in st.comm_bytes
-        else:
-            assert st.comm_bytes["ring_points"] == \
-                nranks * rounds * (n_loc * dim * 4 + n_loc * 4)
-            if overlap:
-                modes = g.meta["ring_schedule"]
-                assert len(modes) == rounds
-                fhops = sum(m == "forest" for m in modes)
-            else:
-                fhops = rounds
-            assert st.comm_bytes["ring_forest"] == \
-                nranks * fhops * forest_hop, (overlap, nranks)
+    g = build_nng(pts, eps, partition="point", traversal=traversal,
+                  k_cap=256)
+    assert g == gb, (traversal, nranks)
+    st, k_fin = g.stats, g.meta["plan"]
+    assert st.elapsed_s > 0 and st.replans == 0
+    # analytic per-channel ring-byte formulas (see nng.py docstring)
+    mirror = nranks * (rounds + 1) * (n_loc * k_fin * 4 + n_loc * 4)
+    assert st.comm_bytes["ring_mirror"] == mirror, traversal
+    assert st.comm_bytes["ring_summary"] == nranks * (dim * 4 + 4)
+    if traversal == "tiles":
+        assert st.comm_bytes["ring_points"] == \
+            nranks * (rounds + 1) * (n_loc * dim * 4 + 4), nranks
+        assert "ring_forest" not in st.comm_bytes
+    else:
+        assert st.comm_bytes["ring_points"] == \
+            nranks * rounds * (n_loc * dim * 4 + n_loc * 4)
+        modes = g.meta["ring_schedule"]
+        assert len(modes) == rounds
+        fhops = sum(m == "forest" for m in modes)
+        assert st.comm_bytes["ring_forest"] == \
+            nranks * fhops * forest_hop, nranks
 
 # forced split schedules: exactness must be schedule-independent, and a
 # "points"->"forest" transition exercises the multi-hop forest jump permute
@@ -594,8 +589,8 @@ print("MESH_PARITY_OK")
 @pytest.mark.parametrize("devices", [3, 5, 6])
 def test_device_parity_and_ring_bytes_meshes(devices):
     """The halving-round schedule and perm_home return hop at odd and
-    non-power-of-two mesh sizes (both parities of nranks), double-buffered
-    AND serial ring bodies, exact vs float64 brute force — plus the
+    non-power-of-two mesh sizes (both parities of nranks), both
+    double-buffered ring bodies, exact vs float64 brute force — plus the
     per-channel ring-byte counters against the analytic formulas, forced
     split schedules (incl. the multi-hop forest jump), and the
     non-shardable-n host-planner error."""
